@@ -148,8 +148,7 @@ def main() -> int:
     if args.fast:
         args.ops = min(args.ops, 600)
 
-    import jax
-
+    from repro import compat
     from repro.serving.router import RouterConfig, cost_model_for
     from repro.table_api import Table
 
@@ -166,7 +165,7 @@ def main() -> int:
     specs = _specs()
     mesh = None
     if "sharded" in placements:
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = compat.make_mesh((4, 2), ("data", "model"))
 
     rows: dict = {}
     cost_models: dict = {}

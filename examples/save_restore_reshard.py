@@ -17,7 +17,7 @@ os.environ.setdefault("XLA_FLAGS",
 import jax                      # noqa: E402
 import numpy as np              # noqa: E402
 
-from repro import Table, TableSpec  # noqa: E402
+from repro import Table, TableSpec, compat  # noqa: E402
 from repro.core.invariants import check_invariants  # noqa: E402
 
 # --- build local: 12 directory bits, ~1500 items ---------------------------
@@ -38,7 +38,7 @@ with tempfile.TemporaryDirectory() as td:
 
     # --- restore sharded: 8 shards consume 3 hash bits, so per-shard
     # dmax=9 gives the same 12-bit aggregate addressing ---------------------
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = compat.make_mesh((1, 8), ("data", "model"))
     sharded_spec = TableSpec(dmax=9, bucket_size=8, pool_size=256,
                              n_lanes=16, placement="sharded", shard_bits=3)
     t8 = Table.restore(path, sharded_spec, mesh)

@@ -1,24 +1,25 @@
-"""JAX version-compatibility shims (sharding + shard_map).
+"""Sharding helpers over the installed JAX (0.9): one spelling per concept.
 
-The repo targets the modern ambient-mesh API (``jax.sharding.set_mesh``,
-``jax.sharding.get_abstract_mesh``, ``jax.shard_map(..., check_vma=)``),
-but must run on older installs (e.g. 0.4.x) where those live elsewhere or
-do not exist. All repo code goes through this module instead of touching
-``jax.sharding`` attributes directly:
+All repo code goes through this module for meshes and ``shard_map``:
 
-  * :func:`set_mesh` — context manager establishing the ambient mesh. Uses
-    the native implementation when present; otherwise keeps its own
-    thread-local stack AND enters the legacy ``with mesh:`` context so
-    pjit-era machinery still resolves bare PartitionSpecs.
+  * :func:`make_mesh` — ``jax.make_mesh`` with every axis ``Auto``. JAX
+    0.9 makes axes ``Explicit`` by default (shardings in the types), under
+    which e.g. flattening a scan's ``[k, n@data]`` status output has no
+    implied sharding and raises. The repo's sharded code relies on
+    compiler-propagated shardings, so every mesh it builds is ``Auto``,
+    and ``Table.create`` refuses any other.
+  * :func:`set_mesh` — context manager establishing the ambient mesh: the
+    native ``jax.sharding.set_mesh`` plus a thread-local stack of the
+    concrete mesh, so :func:`get_abstract_mesh` can hand ``Table.create``
+    a mesh it can place arrays on.
   * :func:`get_abstract_mesh` — the ambient mesh or None (never raises).
-  * :func:`shard_map` — dispatches to ``jax.shard_map`` or
-    ``jax.experimental.shard_map.shard_map``, translating ``check_vma`` to
-    the legacy ``check_rep`` keyword.
+  * :func:`shard_map` — ``jax.shard_map`` with ``check_vma``.
   * :func:`with_spec_constraint` — ``with_sharding_constraint`` that
-    accepts a bare PartitionSpec plus the ambient mesh on every version.
+    accepts a bare PartitionSpec plus the ambient mesh.
 
-``getattr`` (not attribute access) is mandatory here: ``jax.sharding``
-raises AttributeError through its deprecation machinery for unknown names.
+``getattr`` (not attribute access) is mandatory for optional names:
+``jax.sharding`` raises AttributeError through its deprecation machinery
+for unknown names.
 """
 from __future__ import annotations
 
@@ -28,6 +29,17 @@ import threading
 import jax
 
 _local = threading.local()
+
+
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axis types (see module docstring)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=auto,
+                         devices=devices)
+
+
+def is_auto_mesh(mesh) -> bool:
+    return all(t == jax.sharding.AxisType.Auto for t in mesh.axis_types)
 
 
 def _stack():
@@ -41,62 +53,34 @@ def get_abstract_mesh():
     stk = _stack()
     if stk:
         return stk[-1]
-    native = getattr(jax.sharding, "get_abstract_mesh", None)
-    if native is not None:
-        try:
-            mesh = native()
-            if mesh is not None and not getattr(mesh, "empty", True):
-                return mesh
-        except Exception:  # noqa: BLE001 — any failure means "no mesh"
-            pass
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is not None and not mesh.empty:
+        return mesh
     return None
 
 
 @contextlib.contextmanager
 def set_mesh(mesh):
     """Establish ``mesh`` as the ambient mesh for the dynamic extent."""
-    native = getattr(jax.sharding, "set_mesh", None)
     _stack().append(mesh)
     try:
-        if native is not None:
-            with native(mesh):
-                yield mesh
-        elif hasattr(mesh, "__enter__"):
-            with mesh:  # legacy pjit mesh context
-                yield mesh
-        else:
+        with jax.sharding.set_mesh(mesh):
             yield mesh
     finally:
         _stack().pop()
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma=True, **kw):
-    """Version-bridging jax.shard_map (new) / experimental shard_map (old)."""
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        try:
-            return native(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma, **kw)
-        except TypeError:
-            pass
-        try:
-            # mid-generation: top-level jax.shard_map, pre-rename keyword
-            return native(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma, **kw)
-        except TypeError:
-            return native(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as legacy
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma, **kw)
+    """``jax.shard_map`` with the repo's positional mesh convention."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 def with_spec_constraint(x, mesh, spec):
-    """with_sharding_constraint for a bare PartitionSpec on any version.
+    """with_sharding_constraint for a bare PartitionSpec.
 
-    Concrete meshes are bound explicitly through NamedSharding (the only
-    spelling legacy JAX accepts outside a mesh context); abstract meshes
-    fall through to the native spec-based API."""
+    Concrete meshes are bound explicitly through NamedSharding; abstract
+    meshes fall through to the native spec-based API."""
     if isinstance(mesh, jax.sharding.Mesh):
         return jax.lax.with_sharding_constraint(
             x, jax.sharding.NamedSharding(mesh, spec))

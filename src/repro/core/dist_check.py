@@ -21,7 +21,7 @@ from repro.table_api import Table
 
 
 def main():
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = compat.make_mesh((4, 2), ("data", "model"))
     n_glob = 16  # 4 data shards × 4 lanes
 
     # sharded: top hash bit picks the shard, each shard a dmax=8 WF-Ext

@@ -1,42 +1,45 @@
-"""Pallas TPU kernels: the combining apply (PSim hot path), two flavors.
+"""Pallas TPU kernel: the combining apply (PSim hot path), two launchers.
 
-**`grouped_apply`** — the streaming combiner. Ops arrive pre-sorted by
-(bucket, lane) — the linearization order — and pre-partitioned into G
-groups of disjoint pool ranges. Grid step g owns pool rows
-[g·PC, (g+1)·PC): design rule (B) is structural, groups never touch each
-other's rows. Within a group the kernel walks its ops serially (the
-combiner IS serial in PSim) but each op's bucket-row update is a vectorized
-B-lane op; dynamic row addressing uses `pl.dslice` dynamic slices (TPU-legal,
-unlike gathers). The pool blocks are aliased in/out, so the "install" is an
-in-place VMEM update — the CAS-free analogue of PSim's pointer swap. Its
-cost is streaming the ENTIRE pool through VMEM every transaction.
+One kernel (`_apply_kernel`) applies a batch of ops to the pool chunks
+they touch. Its per-op scalars (kinds, keys, values, rows) and the per-step
+chunk ids and op ranges are scalar-prefetch operands, which land in SMEM
+before the body runs: the combiner walks its ops serially (the combiner IS
+serial in PSim) and reads each op's scalars from SMEM, while each op's
+bucket-row update is a vectorized B-lane op on VMEM. Statuses go back
+through an SMEM output.
 
-**`fused_apply`** — the fully-fused write transaction. One kernel program
-does hash → directory route (directory resident in VMEM, as in
-`fused_probe`) → frozen check → per-bucket probe → slot assign (a running
-occupancy accumulator in kernel scratch — the segmented prefix sum over
-each bucket's op group) → masked write-back. The pool stays in HBM
-(`pltpu.ANY`); only the ≤ n_lanes *touched* bucket rows move, via
-double-buffered async DMA: while lane i's bucket row is being combined,
-lane i+1's row is already streaming in (`@pl.when`-guarded prefetch), and
-completed rows stream back out asynchronously, overlapped with later
-combines (a drain loop waits out the tail). Per transaction that is
-O(n_lanes·B) HBM traffic instead of O(P·B) — at P=4096, B=8, n=64 a ~60×
-traffic cut. Duplicate buckets within the batch are linked up front
-(first/last occurrence per lane); every op combines against its bucket's
-*first* fetch (read-your-writes within the batch) and only the *last*
-occurrence writes back — earlier lanes write to the trash row, keeping the
-write-back unconditional and branch-free.
+Ops are stably sorted by pool chunk (PC rows), which keeps lane order
+within every bucket — the linearization order. The grid has one step per
+chunk that holds ops (at most min(G, M) steps; spare steps revisit the
+last chunk with an empty op range), and the pool BlockSpec's index map
+reads the step's chunk id from SMEM, so only touched chunks stream through
+VMEM, double-buffered by the Pallas pipeline: chunk s+1 streams in while
+chunk s combines. Design rule (B) is structural: chunks never touch each
+other's rows. Dynamic row addressing uses `pl.ds` dynamic slices
+(TPU-legal, unlike gathers). The pool blocks are aliased in/out, so the
+"install" is an in-place VMEM update of the chunk — the CAS-free analogue
+of PSim's pointer swap — and later ops of a bucket read earlier ops' writes.
 
-Both kernels never resize: ops that hit a full bucket report ST_FULL and
+**`grouped_apply`** — the streaming combiner over ``[P, B]`` pools with
+tunable chunks (PC=512 by default); its caller routes and masks frozen
+destinations (kernels/ops.py).
+
+**`fused_apply`** — the whole write transaction in one launch: the XLA
+prologue routes (hash → directory entry → bucket) and applies the frozen
+check, and the kernel runs over the full ``[P+1, B]`` pools (trash row
+included) in chunks of one (8, 128) tile: 8 bucket rows. Per transaction
+that moves O(n_lanes·8·B) pool words instead of O(P·B). Manual per-row DMA
+is not an option: Mosaic refuses a row slice of an HBM array whose minor
+dimension (B=8) is narrower than a 128-lane tile.
+
+The kernel never resizes: ops that hit a full bucket report ST_FULL and
 are left for the outer split pass (the paper's FAIL → ResizeWF slow path).
-The fused kernel additionally completes frozen-bucket ops with ST_FROZEN
-in-kernel (paper §4.5) — the grouped kernel leaves that to its wrapper.
+`fused_apply` also completes frozen-bucket ops with ST_FROZEN (paper §4.5).
 
-VMEM, grouped (PC=512, B=8, M=n_lanes ops): pool chunk 2·512·8·4 = 32 KiB,
-op tile ~4·M·4 B. VMEM, fused (dmax≤17, P≤2**17, n≤512): directory
-≤ 512 KiB + frozen ≤ 512 KiB + bucket cache 2·n·B·4 ≤ 2 MiB — the plan
-layer (kernels/plan.py) enforces these bounds.
+Memory (v5e: 1 MiB SMEM, 16 MiB default scoped VMEM; an [X, 8] block pads
+to 128 lanes): SMEM 7·M·4 B + 3·S·4 B (≤ 20 KiB at M=512); VMEM pool
+chunks in+out, double-buffered, 8·PC·128·4 B = 2 MiB at PC=512 and 32 KiB
+at PC=8. The plan layer (kernels/plan.py) enforces the lane bound.
 """
 from __future__ import annotations
 
@@ -47,34 +50,40 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.hashing import dir_index
 from repro.kernels.lookup import _hash_in_kernel
 from repro.kernels.ref import (EMPTY_KEY, ST_FROZEN, ST_FULL,  # noqa: F401
                                ST_IDLE)
 
 _EMPTY = -2147483648  # python int: kernels must not close over traced constants
+FUSED_CHUNK = 8       # fused_apply's pool chunk: one (8, 128) VMEM tile
 
 
-def _apply_kernel(kind_ref, key_ref, val_ref, bid_ref, pk_in, pv_in,
-                  pk_ref, pv_ref, status_ref, *, pc: int, bsize: int):
-    g = pl.program_id(0)
-    # the pool chunk travels through aliased in/out blocks; copy-in once
-    pk_ref[...] = pk_in[...]
-    pv_ref[...] = pv_in[...]
-    m = kind_ref.shape[1]
+def _apply_kernel(gid_s, lo_s, hi_s, kind_s, key_s, val_s, row_s, pk_in,
+                  pv_in, pk_ref, pv_ref, status_ref, *, bsize: int):
+    s = pl.program_id(0)
+
+    # the pool chunk travels through aliased in/out blocks; copy it in on
+    # the step that first visits it (spare steps revisit the last chunk,
+    # whose output block is still resident and holds this call's writes)
+    @pl.when((s == 0) | (gid_s[s] != gid_s[jnp.maximum(s - 1, 0)]))
+    def _copy_in():
+        pk_ref[...] = pk_in[...]
+        pv_ref[...] = pv_in[...]
+
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, bsize), 1)
 
     def body(i, _):
-        kind = kind_ref[0, i]
-        key = key_ref[0, i]
-        value = val_ref[0, i]
-        local = bid_ref[0, i] - g * pc
-
-        row_k = pl.load(pk_ref, (pl.dslice(local, 1), slice(None)))  # [1, B]
-        row_v = pl.load(pv_ref, (pl.dslice(local, 1), slice(None)))
+        kind = kind_s[i]
+        key = key_s[i]
+        value = val_s[i]
+        local = row_s[i]
+        row_k = pk_ref[pl.ds(local, 1), :]                  # [1, B]
+        row_v = pv_ref[pl.ds(local, 1), :]
         occ = row_k != _EMPTY
-        full = occ.all()
+        full = jnp.min(occ.astype(jnp.int32)) > 0
         eq = row_k == key
-        exist = eq.any()
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, bsize), 1)
+        exist = jnp.max(eq.astype(jnp.int32)) > 0
         slot_eq = jnp.sum(jnp.where(eq, lanes, 0))
         slot_free = jnp.min(jnp.where(occ, bsize, lanes))
 
@@ -85,223 +94,87 @@ def _apply_kernel(kind_ref, key_ref, val_ref, bid_ref, pk_in, pv_in,
         do_write = active & ~full & (is_ins | exist)
         slot = jnp.where(is_ins, jnp.where(exist, slot_eq, slot_free), slot_eq)
         sel = (lanes == slot) & do_write
-        new_k = jnp.where(sel, jnp.where(is_ins, key, _EMPTY), row_k)
-        new_v = jnp.where(sel, jnp.where(is_ins, value, 0), row_v)
-        pl.store(pk_ref, (pl.dslice(local, 1), slice(None)), new_k)
-        pl.store(pv_ref, (pl.dslice(local, 1), slice(None)), new_v)
+        pk_ref[pl.ds(local, 1), :] = jnp.where(
+            sel, jnp.where(is_ins, key, _EMPTY), row_k)
+        pv_ref[pl.ds(local, 1), :] = jnp.where(
+            sel, jnp.where(is_ins, value, 0), row_v)
 
-        s = jnp.where(is_ins, (~exist).astype(jnp.int8), exist.astype(jnp.int8))
-        s = jnp.where(blocked, jnp.int8(ST_FULL), s)
-        s = jnp.where(active, s, jnp.int8(ST_IDLE))
-        status_ref[0, i] = s
+        st = jnp.where(is_ins, (~exist).astype(jnp.int32),
+                       exist.astype(jnp.int32))
+        st = jnp.where(blocked, ST_FULL, st)
+        status_ref[i] = jnp.where(active, st, ST_IDLE)
         return 0
 
-    jax.lax.fori_loop(0, m, body, 0)
+    jax.lax.fori_loop(lo_s[s], hi_s[s], body, 0)
+
+
+def _launch(kinds, keys, values, bucket_ids, pool_keys, pool_vals, *, pc: int,
+            interpret: bool):
+    """Sort ops by chunk, build the step schedule, run the kernel over the
+    touched chunks, unscatter statuses. Returns (pk', pv', status i32[M])
+    with ST_IDLE for idle lanes."""
+    M = kinds.shape[0]
+    P, B = pool_keys.shape
+    p_pad = -P % pc
+    pk, pv = pool_keys, pool_vals
+    if p_pad:
+        pk = jnp.pad(pk, ((0, p_pad), (0, 0)), constant_values=EMPTY_KEY)
+        pv = jnp.pad(pv, ((0, p_pad), (0, 0)))
+    G = (P + p_pad) // pc
+    S = min(G, M)                                  # grid steps
+
+    group = jnp.where(kinds != 0, bucket_ids // pc, G)           # G = idle
+    order = jnp.argsort(group, stable=True)                      # keeps lanes
+    gs = group[order]
+    valid = gs < G                                 # a prefix after the sort
+    n_valid = valid.sum().astype(jnp.int32)
+    iota = jnp.arange(M, dtype=jnp.int32)
+    is_start = valid & jnp.concatenate([jnp.ones(1, bool), gs[1:] != gs[:-1]])
+    step = jnp.where(is_start, jnp.cumsum(is_start) - 1, S)
+    lo = jnp.full(S, n_valid, jnp.int32).at[step].set(iota, mode="drop")
+    hi = jnp.concatenate([lo[1:], n_valid[None]])
+    last = jnp.minimum(gs[jnp.maximum(n_valid - 1, 0)], G - 1)
+    gid = jnp.full(S, last, jnp.int32).at[step].set(gs, mode="drop")
+    rows = jnp.where(valid, bucket_ids[order] - gs * pc, 0)
+
+    pool_spec = pl.BlockSpec((pc, B), lambda s, gid, *_: (gid[s], 0))
+    pk, pv, st_sorted = pl.pallas_call(
+        functools.partial(_apply_kernel, bsize=B),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(S,),
+            in_specs=[pool_spec, pool_spec],
+            out_specs=[pool_spec, pool_spec,
+                       pl.BlockSpec(memory_space=pltpu.SMEM)],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(pk.shape, jnp.int32),
+            jax.ShapeDtypeStruct(pv.shape, jnp.int32),
+            jax.ShapeDtypeStruct((M,), jnp.int32),
+        ],
+        input_output_aliases={7: 0, 8: 1},
+        interpret=interpret,
+    )(gid, lo, hi, kinds[order], keys[order], values[order], rows, pk, pv)
+
+    if p_pad:
+        pk, pv = pk[:P], pv[:P]
+    st_sorted = jnp.where(valid, st_sorted, ST_IDLE)
+    status = jnp.full(M, ST_IDLE, jnp.int32).at[order].set(st_sorted)
+    return pk, pv, status
 
 
 @functools.partial(jax.jit, static_argnames=("pc", "interpret"))
 def grouped_apply(kinds, keys, values, bucket_ids, pool_keys, pool_vals, *,
                   pc: int = 512, interpret: bool = True):
-    """Combining apply of ops pre-sorted by (bucket, lane).
+    """Combining apply of ops in (bucket, lane) order within each bucket.
 
-    The wrapper partitions ops into pool-range groups of PC rows, pads each
-    group to the batch width, runs the kernel over the group grid, and
-    unscatters statuses. Returns (pool_keys', pool_vals', status i8[M]).
+    kinds i32[M] (0=idle, 1=insert/upsert, 2=delete), keys/values i32[M],
+    bucket_ids i32[M] rows of the [P, B] pools. Returns
+    (pool_keys', pool_vals', status i8[M]).
     """
-    M = kinds.shape[0]
-    P, B = pool_keys.shape
-    p_pad = -P % pc
-    pk = jnp.pad(pool_keys, ((0, p_pad), (0, 0)), constant_values=EMPTY_KEY)
-    pv = jnp.pad(pool_vals, ((0, p_pad), (0, 0)))
-    G = (P + p_pad) // pc
-
-    group = jnp.where(kinds != 0, bucket_ids // pc, G)           # G = idle bin
-    order = jnp.argsort(group, stable=True)                      # keeps (b, lane)
-    gs = group[order]
-    iota = jnp.arange(M, dtype=jnp.int32)
-    is_start = jnp.concatenate([jnp.ones(1, bool), gs[1:] != gs[:-1]])
-    start = jax.lax.cummax(jnp.where(is_start, iota, -1))
-    rank = iota - start
-    # scatter ops into [G+1, M] padded tiles (row G collects idle lanes)
-    gk = jnp.zeros((G + 1, M), jnp.int32).at[gs, rank].set(kinds[order])
-    gkey = jnp.zeros((G + 1, M), jnp.int32).at[gs, rank].set(keys[order])
-    gval = jnp.zeros((G + 1, M), jnp.int32).at[gs, rank].set(values[order])
-    # padded slots default to their group's base row (kind=0 → no-op read,
-    # but the dynamic slice index must stay in range)
-    gbase = jnp.broadcast_to(
-        (jnp.arange(G + 1, dtype=jnp.int32) * pc)[:, None], (G + 1, M))
-    gbid = gbase.at[gs, rank].set(bucket_ids[order])
-
-    pk_out, pv_out, gstatus = pl.pallas_call(
-        functools.partial(_apply_kernel, pc=pc, bsize=B),
-        grid=(G,),
-        in_specs=[
-            pl.BlockSpec((1, M), lambda g: (g, 0)),      # kinds
-            pl.BlockSpec((1, M), lambda g: (g, 0)),      # keys
-            pl.BlockSpec((1, M), lambda g: (g, 0)),      # values
-            pl.BlockSpec((1, M), lambda g: (g, 0)),      # bucket ids
-            pl.BlockSpec((pc, B), lambda g: (g, 0)),     # pool keys chunk
-            pl.BlockSpec((pc, B), lambda g: (g, 0)),     # pool vals chunk
-        ],
-        out_specs=[
-            pl.BlockSpec((pc, B), lambda g: (g, 0)),
-            pl.BlockSpec((pc, B), lambda g: (g, 0)),
-            pl.BlockSpec((1, M), lambda g: (g, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(pk.shape, jnp.int32),
-            jax.ShapeDtypeStruct(pv.shape, jnp.int32),
-            jax.ShapeDtypeStruct((G, M), jnp.int8),
-        ],
-        interpret=interpret,
-    )(gk[:G], gkey[:G], gval[:G], gbid[:G], pk, pv)
-
-    # unscatter: op at sorted position i lives at (gs[i], rank[i])
-    valid = gs < G
-    st_sorted = jnp.where(valid, gstatus[jnp.minimum(gs, G - 1), rank],
-                          jnp.int8(ST_IDLE))
-    status = jnp.full(M, ST_IDLE, jnp.int8).at[order].set(st_sorted)
-    return pk_out[:P], pv_out[:P], status
-
-
-# ---------------------------------------------------------------------------
-# the fully-fused write transaction
-
-
-def _fused_apply_kernel(kind_ref, key_ref, val_ref, dir_ref, frz_ref,
-                        pk_in, pv_in, pk_hbm, pv_hbm, status_ref, bid_ref,
-                        cache_k, cache_v, act_ref, slot_ref, wb_ref, occ_ref,
-                        fsem, wsem, *, n: int, bsize: int, trash: int,
-                        dmax: int, hash_name: str, hash_shift: int):
-    # the pool is aliased in/out in HBM; every read AND write goes through
-    # the output refs (pk_hbm/pv_hbm) so in-kernel writes are visible to
-    # later reads in interpret mode too (aliased buffers read-your-writes)
-    del pk_in, pv_in
-
-    # --- phase A: scalar route per lane (hash → entry → bucket, frozen) --
-    def route(i, _):
-        k = key_ref[0, i]
-        h = _hash_in_kernel(k, hash_name, hash_shift)
-        e = (h >> jnp.uint32(32 - dmax)).astype(jnp.int32)
-        b = dir_ref[0, e]
-        bid_ref[0, i] = b
-        kind = kind_ref[0, i]
-        act_ref[0, i] = ((kind != 0) & (frz_ref[0, b] == 0)).astype(jnp.int32)
-        return 0
-
-    jax.lax.fori_loop(0, n, route, 0)
-
-    # --- phase A2: duplicate-bucket linkage (vectorized [n, n]) ----------
-    # slot_of[i]: the cache row lane i combines against = its bucket's
-    # FIRST active occurrence (read-your-writes within the batch);
-    # wb_ref[i]: write-back row = the bucket for its LAST occurrence, the
-    # trash row for every other lane (unconditional, collision-free DMA).
-    lane = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    bid = bid_ref[0, :]
-    act = act_ref[0, :] != 0
-    bact = jnp.where(act, bid, -1)
-    same = (bact[:, None] == bact[None, :]) & act[:, None] & act[None, :]
-    first = jnp.min(jnp.where(same, lane, n), axis=1)
-    last = jnp.max(jnp.where(same, lane, -1), axis=1)
-    lane1 = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)[0]
-    slot_ref[0, :] = jnp.where(act, first, lane1)
-    wb_ref[0, :] = jnp.where(act & (last == lane1), bid, trash)
-
-    # --- phase B: double-buffered fetch → combine → async write-back -----
-    # DMA descriptors are reconstructed at wait time from the same scratch
-    # state used at start time (the Pallas idiom: start/wait take identical
-    # (src, dst, sem) triples). Fetches always read the routed bucket row —
-    # a bucket's last write-back is ordered after its last fetch by
-    # construction (fetch occurrences ≤ last occurrence), so a fetch never
-    # races a write-back of the same row; trash-row writes are never read.
-    def fetch(i):
-        b = bid_ref[0, i]
-        return (
-            pltpu.make_async_copy(pk_hbm.at[pl.dslice(b, 1)],
-                                  cache_k.at[pl.dslice(i, 1)], fsem.at[i, 0]),
-            pltpu.make_async_copy(pv_hbm.at[pl.dslice(b, 1)],
-                                  cache_v.at[pl.dslice(i, 1)], fsem.at[i, 1]),
-        )
-
-    def writeback(i):
-        s = slot_ref[0, i]
-        w = wb_ref[0, i]
-        return (
-            pltpu.make_async_copy(cache_k.at[pl.dslice(s, 1)],
-                                  pk_hbm.at[pl.dslice(w, 1)], wsem.at[i, 0]),
-            pltpu.make_async_copy(cache_v.at[pl.dslice(s, 1)],
-                                  pv_hbm.at[pl.dslice(w, 1)], wsem.at[i, 1]),
-        )
-
-    for c in fetch(0):
-        c.start()
-
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, bsize), 1)
-
-    def body(i, _):
-        # double buffering: lane i+1's bucket row streams in while lane i
-        # combines (the only conditional DMA — the final lane has no next)
-        @pl.when(i + 1 < n)
-        def _prefetch():
-            for c in fetch(i + 1):
-                c.start()
-
-        for c in fetch(i):
-            c.wait()
-
-        kind = kind_ref[0, i]
-        key = key_ref[0, i]
-        value = val_ref[0, i]
-        active = act_ref[0, i] != 0
-        s = slot_ref[0, i]
-        row_k = pl.load(cache_k, (pl.dslice(s, 1), slice(None)))  # [1, B]
-        row_v = pl.load(cache_v, (pl.dslice(s, 1), slice(None)))
-        occ_mask = row_k != _EMPTY
-        # running occupancy per bucket group (the segmented prefix sum):
-        # initialized from the fetched row at the group's first lane, then
-        # carried in scratch — ± 1 per applied insert/delete
-        occ = jnp.where(s == i, occ_mask.sum().astype(jnp.int32),
-                        occ_ref[0, s])
-        full = occ >= bsize
-        eq = row_k == key
-        exist = eq.any()
-        slot_eq = jnp.sum(jnp.where(eq, lanes, 0))
-        slot_free = jnp.min(jnp.where(occ_mask, bsize, lanes))
-        is_ins = active & (kind == 1)
-        is_del = active & (kind == 2)
-        blocked = active & full
-        do_write = active & ~full & (is_ins | exist)
-        slot = jnp.where(is_ins, jnp.where(exist, slot_eq, slot_free),
-                         slot_eq)
-        sel = (lanes == slot) & do_write
-        new_k = jnp.where(sel, jnp.where(is_ins, key, _EMPTY), row_k)
-        new_v = jnp.where(sel, jnp.where(is_ins, value, 0), row_v)
-        pl.store(cache_k, (pl.dslice(s, 1), slice(None)), new_k)
-        pl.store(cache_v, (pl.dslice(s, 1), slice(None)), new_v)
-        delta = jnp.where(do_write & is_ins & ~exist, 1,
-                          jnp.where(do_write & is_del & exist, -1, 0))
-        occ_ref[0, s] = occ + delta
-
-        st = jnp.where(is_ins, (~exist).astype(jnp.int32),
-                       exist.astype(jnp.int32))
-        st = jnp.where(blocked, ST_FULL, st)
-        st = jnp.where((kind != 0) & ~active, ST_FROZEN, st)
-        st = jnp.where(kind == 0, ST_IDLE, st)
-        status_ref[0, i] = st
-
-        for c in writeback(i):
-            c.start()
-        return 0
-
-    jax.lax.fori_loop(0, n, body, 0)
-
-    # drain: every write-back must land before the kernel returns
-    def drain(i, _):
-        for c in writeback(i):
-            c.wait()
-        return 0
-
-    jax.lax.fori_loop(0, n, drain, 0)
+    pk, pv, status = _launch(kinds, keys, values, bucket_ids, pool_keys,
+                             pool_vals, pc=pc, interpret=interpret)
+    return pk, pv, status.astype(jnp.int8)
 
 
 @functools.partial(jax.jit, static_argnames=("dmax", "hash_name",
@@ -309,68 +182,32 @@ def _fused_apply_kernel(kind_ref, key_ref, val_ref, dir_ref, frz_ref,
 def fused_apply(directory, frozen, kinds, keys, values, pool_keys, pool_vals,
                 *, dmax: int, hash_name: str = "fmix32", hash_shift: int = 0,
                 interpret: bool = True):
-    """The fully-fused combining write transaction, one kernel launch.
+    """The combining write transaction, one kernel launch.
 
-    directory i32[2**dmax] and frozen bool[P+1] travel whole into VMEM;
-    pool_keys/pool_vals are the FULL [P+1, B] pools (trash row included)
-    and stay in HBM — only routed bucket rows move, by double-buffered DMA.
-    kinds i32[N] (0=idle, 1=insert/upsert, 2=delete), keys/values i32[N].
+    directory i32[2**dmax] and frozen bool[P+1] are read by the XLA
+    prologue (route + frozen check); pool_keys/pool_vals are the FULL
+    [P+1, B] pools (trash row included), of which only the 8-row tiles
+    holding routed buckets move. kinds i32[N] (0=idle, 1=insert/upsert,
+    2=delete), keys/values i32[N].
 
     Returns (pool_keys', pool_vals', status i32[N], bucket_ids i32[N]) with
-    status in {ST_TRUE, ST_FALSE, ST_FULL, ST_FROZEN, ST_IDLE}. The trash
-    row's content is unspecified after the call. Geometry limits are the
-    plan layer's ``fused_apply_supported`` bounds; this wrapper asserts
-    them (they are trace-time shapes).
+    status in {ST_TRUE, ST_FALSE, ST_FULL, ST_FROZEN, ST_IDLE}. Geometry
+    limits are the plan layer's ``fused_apply_supported`` bounds; this
+    wrapper asserts them (they are trace-time shapes).
     """
     from repro.kernels.plan import fused_apply_supported
 
     n = kinds.shape[0]
     p1, b = pool_keys.shape
-    dcap = directory.shape[0]
-    assert dcap == 1 << dmax, (dcap, dmax)
+    assert directory.shape[0] == 1 << dmax, (directory.shape, dmax)
     assert frozen.shape == (p1,), (frozen.shape, p1)
-    assert fused_apply_supported(dmax, p1 - 1, n, b), \
+    assert fused_apply_supported(dmax, p1 - 1, n), \
         f"geometry outside fused-apply bounds: dmax={dmax} P={p1 - 1} n={n} B={b}"
 
-    out = pl.pallas_call(
-        functools.partial(_fused_apply_kernel, n=n, bsize=b, trash=p1 - 1,
-                          dmax=dmax, hash_name=hash_name,
-                          hash_shift=hash_shift),
-        grid=(),
-        in_specs=[
-            pl.BlockSpec((1, n), lambda: (0, 0)),        # kinds
-            pl.BlockSpec((1, n), lambda: (0, 0)),        # keys
-            pl.BlockSpec((1, n), lambda: (0, 0)),        # values
-            pl.BlockSpec((1, dcap), lambda: (0, 0)),     # whole directory
-            pl.BlockSpec((1, p1), lambda: (0, 0)),       # frozen (as i32)
-            pl.BlockSpec(memory_space=pltpu.ANY),        # pool keys (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),        # pool vals (HBM)
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((1, n), lambda: (0, 0)),
-            pl.BlockSpec((1, n), lambda: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((p1, b), jnp.int32),    # pool keys'
-            jax.ShapeDtypeStruct((p1, b), jnp.int32),    # pool vals'
-            jax.ShapeDtypeStruct((1, n), jnp.int32),     # status
-            jax.ShapeDtypeStruct((1, n), jnp.int32),     # bucket ids
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((n, b), jnp.int32),               # bucket cache keys
-            pltpu.VMEM((n, b), jnp.int32),               # bucket cache vals
-            pltpu.VMEM((1, n), jnp.int32),               # active mask
-            pltpu.VMEM((1, n), jnp.int32),               # combine row link
-            pltpu.VMEM((1, n), jnp.int32),               # write-back row
-            pltpu.VMEM((1, n), jnp.int32),               # running occupancy
-            pltpu.SemaphoreType.DMA((n, 2)),             # fetch semaphores
-            pltpu.SemaphoreType.DMA((n, 2)),             # write-back sems
-        ],
-        input_output_aliases={5: 0, 6: 1},
-        interpret=interpret,
-    )(kinds[None, :], keys[None, :], values[None, :], directory[None, :],
-      frozen.astype(jnp.int32)[None, :], pool_keys, pool_vals)
-    pk, pv, status, bids = out
-    return pk, pv, status[0], bids[0]
+    bid = directory[dir_index(_hash_in_kernel(keys, hash_name, hash_shift),
+                              dmax)]
+    frozen_hit = (kinds != 0) & frozen[bid]
+    pk, pv, status = _launch(jnp.where(frozen_hit, 0, kinds), keys, values,
+                             bid, pool_keys, pool_vals, pc=FUSED_CHUNK,
+                             interpret=interpret)
+    return pk, pv, jnp.where(frozen_hit, ST_FROZEN, status), bid

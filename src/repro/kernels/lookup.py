@@ -10,21 +10,25 @@ so each chunk's working set sits in VMEM. Exactly one pool chunk contains a
 query's row, so per-chunk partial results combine by addition — the kernel
 accumulates over the pool-chunk grid dimension.
 
-VMEM budget per program (defaults TQ=256, PC=512, B=8, int32):
-  queries  256·4          =   1 KiB
-  pool     512·8·4·2      =  32 KiB
-  one-hot  256·512·4      = 512 KiB   (fp32 operand for the MXU)
-  out      256·(1+1)·4    =   2 KiB
-→ ~0.6 MiB of 16 MiB VMEM; MXU tiles are (128,128)-aligned by construction.
+Layouts are 2-D throughout (Mosaic has no 1-D vector layouts worth using):
+queries, bucket ids and results travel as [N, 1] columns, so the per-query
+scalars broadcast along lanes without reshaping a vector.
+
+VMEM budget per program (defaults TQ=256, PC=512, B=8, int32; a [X, 1] or
+[X, 8] block pads to 128 lanes):
+  queries + ids + outs  4·256·128·4   = 512 KiB
+  pool    2·512·128·4·2               =   1 MiB (double-buffered)
+  one-hot 256·512·4                   = 512 KiB (fp32 operand for the MXU)
+→ ~2 MiB, well inside v5e's default scoped VMEM.
 
 `fused_probe` additionally fuses hash → directory-route into the kernel:
-the whole directory (i32[2**dmax]) travels into VMEM as a broadcast block
-and the route is the same one-hot MXU idiom, chunked DC entries at a time
-(a static in-kernel loop — bucket ids never materialize in HBM). Extra VMEM
-at dmax=13, DC=512: directory 32 KiB + route one-hot 512 KiB, still < 2 MiB
-total. Directory values must stay below 2**24 (exact fp32 integers); the
-wrapper asserts this. For dmax > FUSED_DMAX_LIMIT callers should fall back
-to the unfused probe (kernels/ops.py does).
+the whole directory travels into VMEM lane-dense as [2**dmax / 128, 128]
+(512 KiB at dmax=17) and the route is a two-level gather — a one-hot MXU
+contraction picks the directory row, a lane mask picks the entry — chunked
+over directory rows in a loop. Directory values must stay below 2**24
+(exact fp32 integers); the wrapper asserts this. For dmax >
+FUSED_DMAX_LIMIT callers should fall back to the unfused probe
+(kernels/ops.py does).
 """
 from __future__ import annotations
 
@@ -39,42 +43,46 @@ from repro.core.hashing import HASH_FNS
 from repro.kernels.ref import EMPTY_KEY  # noqa: F401 (API re-export)
 
 _EMPTY = -2147483648  # python int: kernels must not close over traced constants
+_LANES = 128          # TPU vreg lane width: the directory's minor dimension
+
+
+def _dot(a, b):
+    """Exact fp32 contraction (one-hot operands: a single nonzero term)."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _gather32(onehot, x):
+    """Rows of i32 ``x`` [PC, B] selected by ``onehot`` [TQ, PC] → [TQ, B].
+
+    fp32 matmuls are exact only up to 2**24, so the payload is split into
+    16-bit halves on int32 (arithmetic shift, then mask: both halves are
+    non-negative and below 2**16) and recombined after two contractions."""
+    hi = ((x >> 16) & 0xFFFF).astype(jnp.float32)
+    lo = (x & 0xFFFF).astype(jnp.float32)
+    ghi = _dot(onehot, hi).astype(jnp.int32)
+    glo = _dot(onehot, lo).astype(jnp.int32)
+    return (ghi << 16) | glo
 
 
 def _probe_tile(q, b, pk_ref, pv_ref, found_ref, val_ref, j, pc: int):
     """Shared probe body: accumulate one pool chunk's hits for a query tile.
 
-    One-hot gather via the MXU: [TQ, PC] @ [PC, B] → [TQ, B]. fp32 matmuls
-    are exact only up to 2**24, so 32-bit payloads are split into 16-bit
-    halves (two exact fp32 contractions) and recombined. Used by both the
-    unfused (`_probe_kernel`) and fused (`_fused_probe_kernel`) lookups —
-    keep them in lockstep by construction."""
-    keys = pk_ref[...]                  # [PC, B]
-    vals = pv_ref[...]                  # [PC, B]
-    local = b - j * pc
+    ``q`` and ``b`` are [TQ, 1] columns. One-hot gather via the MXU:
+    [TQ, PC] @ [PC, B] → [TQ, B]. Used by both the unfused (`_probe_kernel`)
+    and fused (`_fused_probe_kernel`) lookups — keep them in lockstep by
+    construction."""
+    local = b - j * pc                                   # [TQ, 1]
     in_chunk = (local >= 0) & (local < pc)
     tq = q.shape[0]
     iota = jax.lax.broadcasted_iota(jnp.int32, (tq, pc), 1)
-    onehot = ((iota == local[:, None]) & in_chunk[:, None]).astype(jnp.float32)
-
-    def gather32(x):
-        xu = x.astype(jnp.uint32)
-        hi = (xu >> 16).astype(jnp.float32)
-        lo = (xu & jnp.uint32(0xFFFF)).astype(jnp.float32)
-        ghi = jax.lax.dot_general(onehot, hi, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        glo = jax.lax.dot_general(onehot, lo, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        out = (ghi.astype(jnp.uint32) << 16) | glo.astype(jnp.uint32)
-        return out.astype(jnp.int32)
-
-    rows_k = gather32(keys)
-    rows_v = gather32(vals)
-    eq = in_chunk[:, None] & (rows_k == q[:, None]) & (q[:, None] != _EMPTY)
-    hit = eq.any(axis=-1)
-    val = jnp.sum(jnp.where(eq, rows_v, 0), axis=-1)
-    found_ref[...] += hit.astype(jnp.int32)
-    val_ref[...] += val
+    onehot = (iota == local).astype(jnp.float32)         # 0-row off-chunk
+    rows_k = _gather32(onehot, pk_ref[...])
+    rows_v = _gather32(onehot, pv_ref[...])
+    eq = in_chunk & (rows_k == q) & (q != _EMPTY)        # [TQ, B]
+    found_ref[...] += jnp.max(eq.astype(jnp.int32), axis=1, keepdims=True)
+    val_ref[...] += jnp.sum(jnp.where(eq, rows_v, 0), axis=1, keepdims=True)
 
 
 def _probe_kernel(q_ref, b_ref, pk_ref, pv_ref, found_ref, val_ref, *, pc: int):
@@ -89,6 +97,21 @@ def _probe_kernel(q_ref, b_ref, pk_ref, pv_ref, found_ref, val_ref, *, pc: int):
                 j, pc)
 
 
+def _pad_queries(x, n_pad, fill):
+    return jnp.pad(x, (0, n_pad), constant_values=fill)[:, None]
+
+
+def _pad_pool(pool_keys, pool_vals, pc):
+    p_pad = -pool_keys.shape[0] % pc
+    pk = jnp.pad(pool_keys, ((0, p_pad), (0, 0)), constant_values=EMPTY_KEY)
+    pv = jnp.pad(pool_vals, ((0, p_pad), (0, 0)))
+    return pk, pv
+
+
+def _column_spec(tq: int):
+    return pl.BlockSpec((tq, 1), lambda i, j: (i, 0))
+
+
 @functools.partial(jax.jit, static_argnames=("tq", "pc", "interpret"))
 def probe(bucket_ids: jnp.ndarray, queries: jnp.ndarray, pool_keys: jnp.ndarray,
           pool_vals: jnp.ndarray, *, tq: int = 256, pc: int = 512,
@@ -99,36 +122,31 @@ def probe(bucket_ids: jnp.ndarray, queries: jnp.ndarray, pool_keys: jnp.ndarray,
     (found bool[N], vals i32[N] with -1 for misses).
     """
     n = queries.shape[0]
-    p, b = pool_keys.shape
     n_pad = -n % tq
-    p_pad = -p % pc
-    q = jnp.pad(queries, (0, n_pad), constant_values=EMPTY_KEY)
-    bid = jnp.pad(bucket_ids, (0, n_pad))
-    pk = jnp.pad(pool_keys, ((0, p_pad), (0, 0)), constant_values=EMPTY_KEY)
-    pv = jnp.pad(pool_vals, ((0, p_pad), (0, 0)))
-    grid = ((n + n_pad) // tq, (p + p_pad) // pc)
+    q = _pad_queries(queries, n_pad, EMPTY_KEY)
+    bid = _pad_queries(bucket_ids, n_pad, 0)
+    pk, pv = _pad_pool(pool_keys, pool_vals, pc)
+    b = pk.shape[1]
+    grid = ((n + n_pad) // tq, pk.shape[0] // pc)
 
     found, val = pl.pallas_call(
         functools.partial(_probe_kernel, pc=pc),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tq,), lambda i, j: (i,)),         # queries
-            pl.BlockSpec((tq,), lambda i, j: (i,)),         # bucket ids
+            _column_spec(tq),                              # queries
+            _column_spec(tq),                              # bucket ids
             pl.BlockSpec((pc, b), lambda i, j: (j, 0)),     # pool keys chunk
             pl.BlockSpec((pc, b), lambda i, j: (j, 0)),     # pool vals chunk
         ],
-        out_specs=[
-            pl.BlockSpec((tq,), lambda i, j: (i,)),
-            pl.BlockSpec((tq,), lambda i, j: (i,)),
-        ],
+        out_specs=[_column_spec(tq), _column_spec(tq)],
         out_shape=[
-            jax.ShapeDtypeStruct((n + n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n + n_pad,), jnp.int32),
+            jax.ShapeDtypeStruct((n + n_pad, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n + n_pad, 1), jnp.int32),
         ],
         interpret=interpret,
     )(q, bid, pk, pv)
-    found = found[:n] > 0
-    return found, jnp.where(found, val[:n], -1)
+    found = found[:n, 0] > 0
+    return found, jnp.where(found, val[:n, 0], -1)
 
 
 # ---------------------------------------------------------------------------
@@ -150,41 +168,54 @@ def _hash_in_kernel(q, hash_name: str, hash_shift: int):
     return h
 
 
+def _directory_shape(dcap: int):
+    """Lane-dense [rows, lanes] view of a 2**dmax directory."""
+    lanes = min(_LANES, dcap)
+    return dcap // lanes, lanes
+
+
 def _fused_probe_kernel(q_ref, dir_ref, pk_ref, pv_ref, found_ref, val_ref,
-                        bid_ref, *, pc: int, dc: int, dcap: int, dmax: int,
+                        bid_ref, *, pc: int, rc: int, dmax: int,
                         hash_name: str, hash_shift: int):
     j = pl.program_id(1)
-    q = q_ref[...]                      # [TQ]
-    tq = q.shape[0]
 
     # --- route: top-dmax hash bits → directory entry → bucket id ---------
     # Depends only on the query tile, so it runs once per tile (the pool
     # grid dim j is innermost — the bid scratch persists across j) and the
-    # remaining pool chunks reuse the stashed ids. The gather is the same
-    # one-hot MXU contraction as the probe, chunked DC directory entries at
-    # a time (static unrolled loop). Directory values < 2**24 are exact in
-    # fp32, so a single contraction suffices.
+    # remaining pool chunks reuse the stashed ids. Entry e sits at
+    # (e // lanes, e % lanes) of the lane-dense directory: a one-hot MXU
+    # contraction gathers the row, RC directory rows per loop step, and a
+    # lane mask picks the entry. Directory values < 2**24 are exact in fp32.
     @pl.when(j == 0)
     def _route():
         found_ref[...] = jnp.zeros_like(found_ref)
         val_ref[...] = jnp.zeros_like(val_ref)
+        q = q_ref[...]                                    # [TQ, 1]
+        tq = q.shape[0]
+        rows, lanes = dir_ref.shape
         h = _hash_in_kernel(q, hash_name, hash_shift)
         e = (h >> jnp.uint32(32 - dmax)).astype(jnp.int32)
-        b = jnp.zeros((tq,), jnp.float32)
-        for c in range(dcap // dc):
-            local = e - c * dc
-            hit = (local >= 0) & (local < dc)
-            iota = jax.lax.broadcasted_iota(jnp.int32, (tq, dc), 1)
-            onehot = ((iota == local[:, None])
-                      & hit[:, None]).astype(jnp.float32)
-            dchunk = dir_ref[c * dc:(c + 1) * dc].astype(jnp.float32)
-            b += jax.lax.dot_general(onehot, dchunk[:, None],
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)[:, 0]
+        shift = lanes.bit_length() - 1
+        row = e >> shift
+        lane = e & (lanes - 1)
+
+        def chunk(c, acc):
+            start = pl.multiple_of(c * rc, rc)
+            d = dir_ref[pl.ds(start, rc), :].astype(jnp.float32)   # [RC, L]
+            iota = jax.lax.broadcasted_iota(jnp.int32, (tq, rc), 1)
+            onehot = (iota == row - start).astype(jnp.float32)
+            return acc + _dot(onehot, d)
+
+        g = jax.lax.fori_loop(0, rows // rc, chunk,
+                              jnp.zeros((tq, lanes), jnp.float32))
+        lane_iota = jax.lax.broadcasted_iota(jnp.int32, (tq, lanes), 1)
+        b = jnp.sum(jnp.where(lane_iota == lane, g, 0.0), axis=1,
+                    keepdims=True)
         bid_ref[...] = b.astype(jnp.int32)
 
     # --- probe: shared tile body, bucket ids from the scratch stash ------
-    _probe_tile(q, bid_ref[...], pk_ref, pv_ref, found_ref, val_ref, j, pc)
+    _probe_tile(q_ref[...], bid_ref[...], pk_ref, pv_ref, found_ref, val_ref,
+                j, pc)
 
 
 @functools.partial(jax.jit, static_argnames=("dmax", "hash_name", "hash_shift",
@@ -195,44 +226,42 @@ def fused_probe(directory: jnp.ndarray, queries: jnp.ndarray,
                 pc: int = 512, dc: int = 512, interpret: bool = True):
     """Single-kernel lookup: hash, directory route, and bucket probe fused.
 
-    directory i32[2**dmax] travels whole into VMEM; bucket ids never touch
-    HBM. Returns (found bool[N], vals i32[N] with -1 for misses).
+    directory i32[2**dmax] travels whole into VMEM (lane-dense); bucket ids
+    never touch HBM. ``dc`` is the route's chunk size in directory entries
+    (rounded to whole, sublane-aligned directory rows). Returns
+    (found bool[N], vals i32[N] with -1 for misses).
     """
     n = queries.shape[0]
-    p, b = pool_keys.shape
+    p = pool_keys.shape[0]
     dcap = directory.shape[0]
     assert dcap == 1 << dmax and dmax <= FUSED_DMAX_LIMIT
     assert p < (1 << 24), "bucket ids must be exact in fp32"
-    dc = min(dc, dcap)
-    assert dcap % dc == 0
+    rows, lanes = _directory_shape(dcap)
+    rc = min(rows, max(8, dc // lanes))
+    assert rows % rc == 0, (rows, rc)
     n_pad = -n % tq
-    p_pad = -p % pc
-    q = jnp.pad(queries, (0, n_pad), constant_values=EMPTY_KEY)
-    pk = jnp.pad(pool_keys, ((0, p_pad), (0, 0)), constant_values=EMPTY_KEY)
-    pv = jnp.pad(pool_vals, ((0, p_pad), (0, 0)))
-    grid = ((n + n_pad) // tq, (p + p_pad) // pc)
+    q = _pad_queries(queries, n_pad, EMPTY_KEY)
+    pk, pv = _pad_pool(pool_keys, pool_vals, pc)
+    b = pk.shape[1]
+    grid = ((n + n_pad) // tq, pk.shape[0] // pc)
 
     found, val = pl.pallas_call(
-        functools.partial(_fused_probe_kernel, pc=pc, dc=dc, dcap=dcap,
-                          dmax=dmax, hash_name=hash_name,
-                          hash_shift=hash_shift),
+        functools.partial(_fused_probe_kernel, pc=pc, rc=rc, dmax=dmax,
+                          hash_name=hash_name, hash_shift=hash_shift),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tq,), lambda i, j: (i,)),          # queries
-            pl.BlockSpec((dcap,), lambda i, j: (0,)),        # whole directory
-            pl.BlockSpec((pc, b), lambda i, j: (j, 0)),      # pool keys chunk
-            pl.BlockSpec((pc, b), lambda i, j: (j, 0)),      # pool vals chunk
+            _column_spec(tq),                                # queries
+            pl.BlockSpec((rows, lanes), lambda i, j: (0, 0)),  # directory
+            pl.BlockSpec((pc, b), lambda i, j: (j, 0)),       # pool keys
+            pl.BlockSpec((pc, b), lambda i, j: (j, 0)),       # pool vals
         ],
-        out_specs=[
-            pl.BlockSpec((tq,), lambda i, j: (i,)),
-            pl.BlockSpec((tq,), lambda i, j: (i,)),
-        ],
+        out_specs=[_column_spec(tq), _column_spec(tq)],
         out_shape=[
-            jax.ShapeDtypeStruct((n + n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n + n_pad,), jnp.int32),
+            jax.ShapeDtypeStruct((n + n_pad, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n + n_pad, 1), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((tq,), jnp.int32)],  # routed bucket ids
+        scratch_shapes=[pltpu.VMEM((tq, 1), jnp.int32)],   # routed bucket ids
         interpret=interpret,
-    )(q, directory, pk, pv)
-    found = found[:n] > 0
-    return found, jnp.where(found, val[:n], -1)
+    )(q, directory.reshape(rows, lanes), pk, pv)
+    found = found[:n, 0] > 0
+    return found, jnp.where(found, val[:n, 0], -1)

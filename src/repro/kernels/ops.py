@@ -4,9 +4,10 @@
 resolves a :class:`~repro.kernels.plan.KernelPlan` once per ``TableSpec``
 (kernels/plan.py) and partials it in here — no env vars or registry reads
 on the hot path. `apply_batch_fused` runs the whole write transaction in
-ONE kernel launch (hash → route → probe → slot-assign → DMA write-back;
-kernels/apply.py); `apply_batch_kernel` is the grouped streaming combiner
-kept as a fallback for geometries outside the fused bounds. Both mirror the
+ONE kernel launch (XLA route + frozen check, then probe → slot-assign →
+write-back over the touched 8-row pool tiles; kernels/apply.py);
+`apply_batch_kernel` is the grouped streaming combiner used for
+geometries outside the fused bounds. Both mirror the
 paper's fast (ApplyWFOp) / slow (ResizeWF) structure: ops reported ST_FULL
 re-enter the reference transaction, which splits.
 
@@ -25,7 +26,8 @@ from repro.core import table as T
 from repro.core.hashing import dir_index
 from repro.kernels import apply as kapply
 from repro.kernels import lookup as klookup
-from repro.kernels.plan import KernelPlan, force_interpret  # noqa: F401
+from repro.kernels.plan import (KernelPlan, force_interpret,  # noqa: F401
+                                fused_lookup_supported)
 from repro.kernels.ref import ST_FROZEN, ST_FULL
 from repro.kernels.tuning import clamp_tiles, pick_tiles, tile_key
 
@@ -54,10 +56,12 @@ def kernels_are_default() -> bool:
 # lookup
 
 
-@partial(jax.jit, static_argnames=("cfg", "interpret", "tq", "pc", "dc"))
+@partial(jax.jit, static_argnames=("cfg", "fused", "interpret", "tq", "pc",
+                                   "dc"))
 def _kernel_lookup_impl(cfg: T.TableConfig, state: T.TableState, queries, *,
-                        tq: int, pc: int, dc: int, interpret: bool):
-    if cfg.dmax <= klookup.FUSED_DMAX_LIMIT and cfg.pool_size < (1 << 24):
+                        fused: bool, tq: int, pc: int, dc: int,
+                        interpret: bool):
+    if fused:
         return klookup.fused_probe(
             state.directory, queries, state.keys[:-1], state.vals[:-1],
             dmax=cfg.dmax, hash_name=cfg.hash_name, hash_shift=cfg.hash_shift,
@@ -82,8 +86,10 @@ def kernel_lookup(cfg: T.TableConfig, state: T.TableState, queries, *,
                        key=tile_key("lookup", dmax=cfg.dmax,
                                     pool_size=cfg.pool_size,
                                     n_lanes=max(cfg.n_lanes, 8)))
-    return _kernel_lookup_impl(cfg, state, queries, tq=tiles.tq, pc=tiles.pc,
-                               dc=tiles.dc, interpret=interpret)
+    return _kernel_lookup_impl(
+        cfg, state, queries,
+        fused=fused_lookup_supported(cfg.dmax, cfg.pool_size), tq=tiles.tq,
+        pc=tiles.pc, dc=tiles.dc, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -94,7 +100,6 @@ def kernel_lookup(cfg: T.TableConfig, state: T.TableState, queries, *,
          donate_argnums=1)
 def _apply_batch_kernel_impl(cfg: T.TableConfig, state: T.TableState,
                              ops: T.OpBatch, *, pc: int, interpret: bool):
-    n = cfg.n_lanes
     fresh = (ops.kind != T.NOP) & (ops.seq > state.applied_seq)
     replay = (ops.kind != T.NOP) & ~fresh
 
@@ -105,14 +110,11 @@ def _apply_batch_kernel_impl(cfg: T.TableConfig, state: T.TableState,
     frozen_hit = fresh & state.frozen[bid]
     live = fresh & ~frozen_hit
     kinds = jnp.where(live, ops.kind, 0)
-    # sort by (bucket, lane) = linearization order; stable keeps lane order
-    order = jnp.argsort(jnp.where(live, bid, jnp.int32(cfg.pool_size + 1)),
-                        stable=True)
-    inv = jnp.zeros(n, jnp.int32).at[order].set(jnp.arange(n, dtype=jnp.int32))
-    pk, pv, status_sorted = kapply.grouped_apply(
-        kinds[order], ops.key[order], ops.value[order], bid[order],
-        state.keys[:-1], state.vals[:-1], pc=pc, interpret=interpret)
-    status = status_sorted[inv]
+    # lanes go in lane order: the kernel's stable sort by pool chunk keeps
+    # it within every bucket (the linearization order)
+    pk, pv, status = kapply.grouped_apply(
+        kinds, ops.key, ops.value, bid, state.keys[:-1], state.vals[:-1],
+        pc=pc, interpret=interpret)
 
     applied = live & (status != ST_FULL)
     hit = applied & (status == jnp.int8(T.TRUE))
@@ -246,8 +248,9 @@ def plan_lookup(plan: KernelPlan, cfg: T.TableConfig, state: T.TableState,
         return T.lookup(cfg, state, queries)
     t = clamp_tiles(plan.lookup_tiles, queries.shape[0], cfg.pool_size,
                     cfg.dcap)
-    return _kernel_lookup_impl(cfg, state, queries, tq=t.tq, pc=t.pc,
-                               dc=t.dc, interpret=plan.interpret)
+    return _kernel_lookup_impl(cfg, state, queries, fused=plan.fused_lookup,
+                               tq=t.tq, pc=t.pc, dc=t.dc,
+                               interpret=plan.interpret)
 
 
 def plan_apply(plan: KernelPlan, cfg: T.TableConfig, state: T.TableState,

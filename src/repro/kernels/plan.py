@@ -25,12 +25,13 @@ Changing the environment after a spec is constructed does not change that
 spec's plan — construct a new spec (the point: a live table's dispatch is
 immutable and inspectable via ``Table.plan()``).
 
-Fused-apply eligibility: the fully-fused kernel keeps the directory
-(``4·2**dmax`` bytes), the frozen vector (``4·(P+1)``), and an
-``n_lanes × B`` bucket cache resident in VMEM, and spends one DMA
-semaphore pair per lane — the guards below keep all of that comfortably
-under budget. Outside them the plan falls back to the grouped apply kernel
-(and the XLA single-pass transaction remains the ``xla`` backend).
+Fused-apply eligibility: the single-launch apply routes in XLA and moves
+only the 8-row pool tiles it touches, so its one hard bound is the per-lane
+scalars it keeps in SMEM (``n_lanes`` ≤ 512). The plan pairs it with the
+fused lookup's geometry (``dmax`` ≤ 17, ``P+1`` ≤ 2**17); beyond that the
+grouped apply kernel streams 512-row chunks (and the XLA single-pass
+transaction remains the ``xla`` backend). Whether the fused apply should
+take the larger geometries too is open until it is measured on the chip.
 """
 from __future__ import annotations
 
@@ -44,10 +45,9 @@ from repro.kernels.tuning import (TileConfig, autotune, cached_tiles,
 PLAN_BACKENDS = ("xla", "pallas")
 AUTOTUNE_POLICIES = ("off", "measured")
 
-# fused-apply VMEM guards (see module docstring)
-FUSED_APPLY_POOL_LIMIT = 1 << 17   # frozen vector rows resident in VMEM
-FUSED_APPLY_MAX_LANES = 512        # per-lane DMA semaphores + bucket cache
-FUSED_APPLY_MAX_CACHE = 1 << 16    # n_lanes * bucket_size cache entries
+# fused-apply guards (see module docstring)
+FUSED_APPLY_POOL_LIMIT = 1 << 17   # pool rows (trash row included)
+FUSED_APPLY_MAX_LANES = 512        # per-lane scalars in SMEM
 
 _TUNE_ITERS = 3
 
@@ -94,12 +94,10 @@ def fused_lookup_supported(dmax: int, pool_size: int) -> bool:
     return dmax <= FUSED_DMAX_LIMIT and pool_size < (1 << 24)
 
 
-def fused_apply_supported(dmax: int, pool_size: int, n_lanes: int,
-                          bucket_size: int) -> bool:
+def fused_apply_supported(dmax: int, pool_size: int, n_lanes: int) -> bool:
     return (dmax <= FUSED_DMAX_LIMIT
             and pool_size + 1 <= FUSED_APPLY_POOL_LIMIT
-            and 0 < n_lanes <= FUSED_APPLY_MAX_LANES
-            and n_lanes * bucket_size <= FUSED_APPLY_MAX_CACHE)
+            and 0 < n_lanes <= FUSED_APPLY_MAX_LANES)
 
 
 def _measured_tiles(kind: str, cfg, backend_tag: str, interpret: bool,
@@ -126,6 +124,7 @@ def _measured_tiles(kind: str, cfg, backend_tag: str, interpret: bool,
             from repro.kernels import ops as kops
             out = kops._kernel_lookup_impl(
                 cfg, state, jax.numpy.arange(n_queries, dtype=jax.numpy.int32),
+                fused=fused_lookup_supported(cfg.dmax, cfg.pool_size),
                 tq=tiles.tq, pc=tiles.pc, dc=tiles.dc, interpret=interpret)
         else:
             from repro.kernels import apply as kapply
@@ -171,7 +170,7 @@ def resolve_plan(spec) -> KernelPlan:
                     and fused_lookup_supported(cfg.dmax, cfg.pool_size))
     fused_apply = (backend == "pallas"
                    and fused_apply_supported(cfg.dmax, cfg.pool_size,
-                                             spec.n_lanes, cfg.bucket_size)
+                                             spec.n_lanes)
                    and os.environ.get("REPRO_FUSED_APPLY", "") != "0")
 
     policy = os.environ.get("REPRO_AUTOTUNE") or getattr(

@@ -23,9 +23,9 @@ strongest first:
 ``autotune`` is the **measured** sweep: it times candidate tile shapes with
 a caller-supplied runner and persists the winner in an on-disk JSON cache
 keyed by ``(backend tag, plan key)`` — so per ``(shape, backend)`` the sweep
-runs once per machine, and every later plan resolution is a cache hit. The
-cache lives at ``REPRO_TUNE_CACHE`` (default
-``~/.cache/repro/tile_cache.json``).
+runs once per checkout, and every later plan resolution is a cache hit. The
+cache lives at ``REPRO_TUNE_CACHE`` (default ``<checkout>/.tile_cache.json``,
+next to the compile cache — see ``repro/caches.py``).
 """
 from __future__ import annotations
 
@@ -36,6 +36,8 @@ import re
 import time
 from pathlib import Path
 from typing import Callable, Dict, Iterable, Optional
+
+from repro.caches import TILE_CACHE_PATH
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,11 +168,11 @@ def default_candidates(n_queries: int, pool_size: int,
 
 
 def cache_path() -> Path:
-    """``REPRO_TUNE_CACHE`` or ``~/.cache/repro/tile_cache.json``."""
+    """``REPRO_TUNE_CACHE`` or ``<checkout>/.tile_cache.json``."""
     env = os.environ.get("REPRO_TUNE_CACHE")
     if env:
         return Path(env)
-    return Path.home() / ".cache" / "repro" / "tile_cache.json"
+    return TILE_CACHE_PATH
 
 
 def _load_cache(path: Path) -> dict:
@@ -213,8 +215,10 @@ def autotune(key: str, candidates: Iterable[TileConfig],
     registered and returned. On a miss, ``run`` is timed per candidate
     (``run`` must block until the work is done, e.g. via
     ``jax.block_until_ready``; the first call per candidate is warmup),
-    and the argmin is registered, persisted, and returned. Candidates that
-    raise just lose the sweep (illegal tile shapes are not fatal).
+    and the argmin is registered, persisted, and returned. A candidate that
+    raises just loses the sweep (an illegal tile shape is not fatal), but if
+    every candidate raises the sweep raises too: on a chip that means the
+    compiler refused the kernel, and a default tile would only hide it.
     """
     validate_key(key)
     if not backend_tag:
@@ -227,6 +231,7 @@ def autotune(key: str, candidates: Iterable[TileConfig],
             register_tiles(key, hit, override=True)
             return hit
     best, best_t = None, float("inf")
+    errors = []
     for tiles in candidates:
         try:
             run(tiles)  # warmup/compile
@@ -234,18 +239,21 @@ def autotune(key: str, candidates: Iterable[TileConfig],
             for _ in range(max(1, iters)):
                 run(tiles)
             dt = (time.perf_counter() - t0) / max(1, iters)
-        except Exception:  # noqa: BLE001 — illegal tile shapes just lose
+        except Exception as e:  # noqa: BLE001 — illegal tile shapes just lose
+            errors.append(f"{tiles}: {type(e).__name__}: {e}")
             continue
         if dt < best_t:
             best, best_t = tiles, dt
     if best is None:
-        best = TileConfig()
+        raise RuntimeError(
+            f"autotune {key!r} on {backend_tag!r}: every candidate failed\n"
+            + "\n".join(errors))
     register_tiles(key, best, override=True)
     if use_cache:
         data = _load_cache(path)
         data[f"{backend_tag}::{key}"] = {
             "tiles": dataclasses.asdict(best),
-            "mean_s": best_t if best_t < float("inf") else None,
+            "mean_s": best_t,
             "iters": iters,
             "measured_at": time.time(),
         }

@@ -8,15 +8,15 @@ only, no TP traffic across pods).
 """
 from __future__ import annotations
 
-import jax
+from repro import compat
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return compat.make_mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1, data: int = 1):
     """Small mesh for tests on host devices."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return compat.make_mesh((data, model), ("data", "model"))

@@ -167,7 +167,8 @@ class Table:
         """Initialize an empty table for ``spec`` (eager; not jit-safe).
 
         Sharded placement requires ``mesh`` (or an ambient mesh from
-        ``compat.set_mesh``) with the spec's data/model axes; the stacked
+        ``compat.set_mesh``) with the spec's data/model axes, built with
+        ``Auto`` axis types (``compat.make_mesh``); the stacked
         per-shard states are placed P(model_axis), slabs replicated.
         """
         if spec.placement == "sharded":
@@ -175,6 +176,9 @@ class Table:
             if mesh is None:
                 mesh = compat.get_abstract_mesh()
             assert mesh is not None, "sharded placement needs a mesh"
+            assert compat.is_auto_mesh(mesh), (
+                "sharded placement needs a mesh with Auto axis types "
+                "(repro.compat.make_mesh)")
             assert mesh.shape[spec.model_axis] == spec.n_shards, (
                 f"mesh axis {spec.model_axis!r}={mesh.shape[spec.model_axis]}"
                 f" != n_shards={spec.n_shards}")

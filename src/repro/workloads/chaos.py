@@ -147,12 +147,14 @@ def default_mesh_for(n_shards: int, n_lanes: int = 16):
     cannot host ``n_shards`` table shards (the candidate is skipped)."""
     import jax
 
+    from repro import compat
+
     ndev = len(jax.devices())
     if n_shards < 2 or ndev % n_shards or ndev < n_shards:
         return None
     if n_lanes % (ndev // n_shards):
         return None
-    return jax.make_mesh((ndev // n_shards, n_shards), ("data", "model"))
+    return compat.make_mesh((ndev // n_shards, n_shards), ("data", "model"))
 
 
 def _respec_candidates(spec, mesh, mesh_for) -> List[Tuple[object, object]]:

@@ -19,7 +19,6 @@ HERE = os.path.abspath(__file__)
 
 
 def _parity_main():
-    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -29,7 +28,7 @@ def _parity_main():
     from repro.core.spec import TableSpec
     from repro.table_api import Table
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = compat.make_mesh((4, 2), ("data", "model"))
     n = 16
 
     # --- scalar parity: sharded vs local vs sequential reference ---------

@@ -113,12 +113,12 @@ def test_plan_is_hashable_and_source_free():
 
 
 def test_fused_geometry_guards():
-    assert fused_apply_supported(6, 64, 8, 4)
-    assert not fused_apply_supported(18, 64, 8, 4)          # directory
-    assert not fused_apply_supported(6, 1 << 18, 8, 4)      # frozen vector
-    assert not fused_apply_supported(6, 64, 1024, 4)        # lane sems
-    assert not fused_apply_supported(6, 64, 512, 256)       # bucket cache
-    assert not fused_apply_supported(6, 64, 0, 4)
+    assert fused_apply_supported(6, 64, 8)
+    assert not fused_apply_supported(18, 64, 8)          # lookup's dmax
+    assert not fused_apply_supported(6, 1 << 18, 8)      # pool rows
+    assert not fused_apply_supported(6, 64, 1024)        # SMEM scalars
+    assert fused_apply_supported(6, 64, 512)
+    assert not fused_apply_supported(6, 64, 0)
     assert fused_lookup_supported(17, 64)
     assert not fused_lookup_supported(18, 64)
     # a spec outside the apply guard still plans fused lookups
@@ -185,6 +185,49 @@ def test_autotune_skips_raising_candidates(tmp_path):
                           iters=1, backend_tag="x",
                           path=tmp_path / "c.json")
     assert win == good
+
+
+def test_autotune_raises_when_every_candidate_fails(tmp_path):
+    """No silent default: a sweep in which every candidate raises (on a
+    chip: the compiler refused the kernel) must raise, naming the errors,
+    and must persist nothing."""
+    key = tuning.tile_key("apply", dmax=4, pool_size=16, n_lanes=8)
+
+    def run(t):
+        raise RuntimeError("Mosaic refused the kernel")
+
+    path = tmp_path / "c.json"
+    with pytest.raises(RuntimeError, match="every candidate failed"):
+        tuning.autotune(key, [tuning.TileConfig(8, 8, 16)], run, iters=1,
+                        backend_tag="x", path=path)
+    assert not path.exists()
+
+
+def test_tile_cache_defaults_into_the_checkout(monkeypatch):
+    from repro import caches
+
+    monkeypatch.delenv("REPRO_TUNE_CACHE", raising=False)
+    assert tuning.cache_path() == caches.TILE_CACHE_PATH
+    assert caches.TILE_CACHE_PATH.parent == caches.COMPILE_CACHE_DIR.parent
+    assert (caches.CHECKOUT / "src" / "repro" / "caches.py").exists()
+
+
+def test_compile_cache_rule(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is JAX's own to read: nothing
+    overrides it. Unset, the cache goes to the checkout's fixed path."""
+    from repro import caches
+
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert caches.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == old
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert caches.enable_compile_cache() == str(caches.COMPILE_CACHE_DIR)
+        assert (jax.config.jax_compilation_cache_dir
+                == str(caches.COMPILE_CACHE_DIR))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
 
 
 def test_measured_policy_end_to_end(tmp_path, monkeypatch):

@@ -291,7 +291,8 @@ def test_policy_stats_and_depth_sharded():
 
 
 def _sharded_main():
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro import compat
+    mesh = compat.make_mesh((4, 2), ("data", "model"))
     pol = ResizePolicy(split_watermark=0.75, merge_watermark=0.375,
                        max_splits=8, max_merges=4)
     spec = TableSpec(dmax=8, bucket_size=8, pool_size=256, n_lanes=16,

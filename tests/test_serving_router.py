@@ -237,14 +237,13 @@ def test_closed_loop_sharded():
 
 
 def _sharded_main():
-    import jax
-
+    from repro import compat
     from repro.core.policy import ResizePolicy
     from repro.serving.router import RouterConfig, default_cost_model
     from repro.table_api import TableSpec
     from repro.workloads import serve_closed_loop
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = compat.make_mesh((4, 2), ("data", "model"))
     spec = TableSpec(dmax=8, bucket_size=8, pool_size=256, n_lanes=8,
                      placement="sharded", shard_bits=1,
                      resize_policy=ResizePolicy())

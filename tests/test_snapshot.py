@@ -316,8 +316,9 @@ def _reshard_main() -> int:
     rng = np.random.default_rng(21)
     keys = rng.choice(np.arange(1, 1 << 24), size=700,
                       replace=False).astype(np.int32)
-    mesh8 = jax.make_mesh((1, 8), ("data", "model"))
-    mesh4 = jax.make_mesh((2, 4), ("data", "model"))
+    from repro import compat
+    mesh8 = compat.make_mesh((1, 8), ("data", "model"))
+    mesh4 = compat.make_mesh((2, 4), ("data", "model"))
     schema = {"page": jnp.int32}
     sizes = []
 

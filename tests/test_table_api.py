@@ -108,10 +108,61 @@ def test_facade_sharded(backend):
 
 
 def _sharded_main(backend: str):
-    import jax
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro import compat
+    mesh = compat.make_mesh((4, 2), ("data", "model"))
     assert _facade_body(backend, "sharded", mesh)
     print("sharded facade OK")
+    return 0
+
+
+@pytest.mark.subprocess
+def test_sharded_multichunk_apply_under_ambient_mesh():
+    """A batch of several n_lanes chunks on a sharded table, inside
+    ``compat.set_mesh``: the scan's stacked [chunks, n_lanes] statuses must
+    flatten back to one status per op (under JAX 0.9's default Explicit
+    mesh axes that reshape raised ``ShardingTypeError``)."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(HERE), "..", "src"))
+    proc = subprocess.run(
+        [sys.executable, HERE, "--run-multichunk"],
+        env=env, capture_output=True, text=True, timeout=900)
+    assert proc.returncode == 0, (proc.stdout[-3000:], proc.stderr[-3000:])
+    assert "sharded multichunk OK" in proc.stdout
+
+
+def _multichunk_main():
+    import jax
+    from repro import compat
+    from repro.table_api import Table, TableSpec
+
+    spec = TableSpec(dmax=8, bucket_size=4, pool_size=256, n_lanes=N_LANES,
+                     placement="sharded", shard_bits=1)
+    with pytest.raises(AssertionError, match="Auto axis types"):
+        Table.create(spec, jax.make_mesh((4, 2), ("data", "model")))
+    mesh = compat.make_mesh((4, 2), ("data", "model"))
+    t = Table.create(spec, mesh)
+    lo = Table.create(TableSpec(dmax=9, bucket_size=4, pool_size=512,
+                                n_lanes=N_LANES))
+    keys = np.arange(1, 3 * N_LANES + 6, dtype=np.int32)   # 4 chunks
+    with compat.set_mesh(mesh):
+        t, res = t.insert(keys, keys * 3)
+        lo, res_lo = lo.insert(keys, keys * 3)
+        assert res.status.shape == keys.shape
+        assert (np.asarray(res.status) == 1).all()
+        np.testing.assert_array_equal(np.asarray(res.status),
+                                      np.asarray(res_lo.status))
+        kinds = np.where(keys % 2 == 0, 2, 1).astype(np.int32)  # mixed
+        t, res = t.apply(kinds, keys, keys)
+        lo, res_lo = lo.apply(kinds, keys, keys)
+        np.testing.assert_array_equal(np.asarray(res.status),
+                                      np.asarray(res_lo.status))
+        found, val = t.lookup(keys)
+    assert (np.asarray(found) == (keys % 2 == 1)).all()
+    assert (np.asarray(val)[keys % 2 == 1] == keys[keys % 2 == 1]).all()
+    assert int(t.size()) == int(lo.size())
+    print("sharded multichunk OK")
     return 0
 
 
@@ -257,5 +308,7 @@ def test_facade_threads_through_jit_and_scan():
 
 
 if __name__ == "__main__":
+    if sys.argv[1] == "--run-multichunk":
+        sys.exit(_multichunk_main())
     assert sys.argv[1] == "--run-sharded", sys.argv
     sys.exit(_sharded_main(sys.argv[2]))

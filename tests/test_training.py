@@ -116,7 +116,8 @@ def test_elastic_restore_changes_sharding(tmp_path):
     state = init_train_state(cfg, jax.random.key(3))
     ck = str(tmp_path / "ck")
     C.save(ck, 1, state)
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro import compat
+    mesh = compat.make_mesh((1,), ("data",))
     shardings = jax.tree.map(
         lambda _: NamedSharding(mesh, P()), jax.eval_shape(lambda: state))
     restored, _ = C.restore(ck, 1, jax.eval_shape(lambda: state), shardings)

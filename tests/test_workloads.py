@@ -139,10 +139,10 @@ def test_scenario_replay_parity_sharded():
 
 
 def _sharded_main() -> int:
-    import jax
+    from repro import compat
     from repro.workloads import get_scenario, replay
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = compat.make_mesh((4, 2), ("data", "model"))
     reports = {}
     for name in BASE_SCENARIOS:
         # reduced scale: shard_map on a forced-8-device CPU host is slow,
