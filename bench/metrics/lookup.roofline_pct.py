@@ -1,0 +1,14 @@
+"""Share of the HBM roofline the probe kernels reach: the bytes the
+lookups of the traced window need (harness/work.py), at the peak
+bandwidth of the cell's chips, over the probe kernels' device time."""
+from harness import work
+
+
+def read(run):
+    t, c = run.trace, run.trace_counters
+    if not t or not t.get("devices") or not c or not run.peaks:
+        return None
+    return work.roofline_pct(
+        c.get("read_ops", 0), work.lookup_bytes(run.spec.bucket_size),
+        t["category_s"]["lookup"],
+        run.peaks["hbm_bytes_per_s"] * t["devices"])
