@@ -51,7 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hashing import dir_index
-from repro.kernels.lookup import _hash_in_kernel
+from repro.kernels.lookup import _hash_in_kernel, tile_schedule
 from repro.kernels.ref import (EMPTY_KEY, ST_FROZEN, ST_FULL,  # noqa: F401
                                ST_IDLE)
 
@@ -124,17 +124,7 @@ def _launch(kinds, keys, values, bucket_ids, pool_keys, pool_vals, *, pc: int,
     S = min(G, M)                                  # grid steps
 
     group = jnp.where(kinds != 0, bucket_ids // pc, G)           # G = idle
-    order = jnp.argsort(group, stable=True)                      # keeps lanes
-    gs = group[order]
-    valid = gs < G                                 # a prefix after the sort
-    n_valid = valid.sum().astype(jnp.int32)
-    iota = jnp.arange(M, dtype=jnp.int32)
-    is_start = valid & jnp.concatenate([jnp.ones(1, bool), gs[1:] != gs[:-1]])
-    step = jnp.where(is_start, jnp.cumsum(is_start) - 1, S)
-    lo = jnp.full(S, n_valid, jnp.int32).at[step].set(iota, mode="drop")
-    hi = jnp.concatenate([lo[1:], n_valid[None]])
-    last = jnp.minimum(gs[jnp.maximum(n_valid - 1, 0)], G - 1)
-    gid = jnp.full(S, last, jnp.int32).at[step].set(gs, mode="drop")
+    order, gs, valid, lo, hi, gid = tile_schedule(group, G, S)
     rows = jnp.where(valid, bucket_ids[order] - gs * pc, 0)
 
     pool_spec = pl.BlockSpec((pc, B), lambda s, gid, *_: (gid[s], 0))

@@ -1,34 +1,60 @@
-"""Pallas TPU kernel: the sync-free bucket probe (design rule A hot path).
+"""Pallas TPU kernels: the sync-free bucket probe (design rule A hot path).
 
 Lookups are the paper's most frequent operation; its design rule (A) demands
 they run with zero synchronization. On TPU the probe is a *gather* problem:
-query → pool row → B-way compare. GPUs would scatter-gather; the TPU-native
-idiom is a **tiled one-hot contraction on the MXU**: a [TQ, PC] one-hot of
+query → pool row → B-way compare. Two kernels solve it in two ways.
+
+**`probe`** — the routed probe. Its caller routes (hash → directory →
+bucket id, in XLA), so the bucket ids exist before the grid starts and the
+kernel fetches only the pool tiles that hold them, the pattern of the apply
+kernels (kernels/apply.py). The pools sit in HBM with the bucket axis minor
+(XLA lays a ``[P+1, B]`` array out as ``{0,1:T(8,128)}`` so that B=8 does
+not pad to 128 lanes), so the kernel reads them through their transposed
+view ``[B, P+1]``, a free bitcast; a row-major block would make XLA
+transpose the whole pool into a 128-lane-padded copy on every call. One
+``(B, 128)`` VMEM tile then holds the slots of 128 consecutive buckets.
+Queries are stably sorted by tile; the step schedule (tile id, query range)
+and each query's key and lane are scalar-prefetch operands in SMEM, and the
+pool BlockSpecs' index maps read the step's tile id, so only touched tiles
+stream through VMEM, double-buffered by the pipeline, and consecutive
+queries on one tile fetch it once. The body walks its queries serially: a
+lane mask picks the bucket's column, an equality compare the slot, and
+found flag and value go to SMEM outputs; the wrapper un-permutes them. No
+one-hot and no MXU. The pools go in whole, trash row included (the
+directory never routes there).
+
+Memory per launch of N ≤ 8192 queries (v5e: 1 MiB SMEM, 16 MiB default
+scoped VMEM): SMEM (2·N + 3·S)·4 B of scalar prefetch (keys, lanes; tile
+id, range start and end per step, S = min(tiles, N) steps) and 2·N·4 B of
+outputs, ≤ 224 KiB at N = 8192; VMEM two pools × two buffers of one
+(8, 128) int32 tile = 16 KiB at B = 8. Longer batches run in launches of
+8192 queries.
+
+**`fused_probe`** additionally fuses hash → directory-route into the
+kernel, so its bucket ids do not exist before the grid starts and an index
+map cannot read them: it sweeps the pool. The directory travels into VMEM
+lane-dense as [2**dmax / 128, 128] (512 KiB at dmax=17) and the route is a
+two-level gather — a one-hot MXU contraction picks the directory row, a
+lane mask picks the entry — chunked over directory rows in a loop. The
+probe is a **tiled one-hot contraction on the MXU**: a [TQ, PC] one-hot of
 local bucket ids multiplied into the [PC, B] pool chunk materializes the
-gathered rows in registers, with the grid tiling the (queries × pool) space
-so each chunk's working set sits in VMEM. Exactly one pool chunk contains a
-query's row, so per-chunk partial results combine by addition — the kernel
-accumulates over the pool-chunk grid dimension.
+gathered rows in registers, with the grid tiling the (queries × pool)
+space so each chunk's working set sits in VMEM. Exactly one pool chunk
+contains a query's row, so per-chunk partial results combine by addition —
+the kernel accumulates over the pool-chunk grid dimension. Queries, bucket
+ids and results travel as [N, 1] columns (Mosaic has no 1-D vector layouts
+worth using), so the per-query scalars broadcast along lanes. Directory
+values must stay below 2**24 (exact fp32 integers); the wrapper asserts
+this. For dmax > FUSED_DMAX_LIMIT callers fall back to the routed probe
+(kernels/ops.py does).
 
-Layouts are 2-D throughout (Mosaic has no 1-D vector layouts worth using):
-queries, bucket ids and results travel as [N, 1] columns, so the per-query
-scalars broadcast along lanes without reshaping a vector.
-
-VMEM budget per program (defaults TQ=256, PC=512, B=8, int32; a [X, 1] or
-[X, 8] block pads to 128 lanes):
-  queries + ids + outs  4·256·128·4   = 512 KiB
+VMEM budget of `fused_probe` (defaults TQ=256, PC=512, B=8, int32; a
+[X, 1] or [X, 8] block pads to 128 lanes):
+  queries + outs + ids  4·256·128·4   = 512 KiB
   pool    2·512·128·4·2               =   1 MiB (double-buffered)
   one-hot 256·512·4                   = 512 KiB (fp32 operand for the MXU)
-→ ~2 MiB, well inside v5e's default scoped VMEM.
-
-`fused_probe` additionally fuses hash → directory-route into the kernel:
-the whole directory travels into VMEM lane-dense as [2**dmax / 128, 128]
-(512 KiB at dmax=17) and the route is a two-level gather — a one-hot MXU
-contraction picks the directory row, a lane mask picks the entry — chunked
-over directory rows in a loop. Directory values must stay below 2**24
-(exact fp32 integers); the wrapper asserts this. For dmax >
-FUSED_DMAX_LIMIT callers should fall back to the unfused probe
-(kernels/ops.py does).
+  directory                           ≤ 512 KiB
+→ ~2.5 MiB, well inside v5e's default scoped VMEM.
 """
 from __future__ import annotations
 
@@ -43,7 +69,113 @@ from repro.core.hashing import HASH_FNS
 from repro.kernels.ref import EMPTY_KEY  # noqa: F401 (API re-export)
 
 _EMPTY = -2147483648  # python int: kernels must not close over traced constants
-_LANES = 128          # TPU vreg lane width: the directory's minor dimension
+_LANES = 128          # TPU vreg lane width: minor dim of the directory and pool tiles
+_MAX_QUERIES = 8192   # routed-probe queries per launch: their scalars in SMEM
+
+
+def tile_schedule(group, n_groups: int, n_steps: int):
+    """Step schedule over the tiles that lanes name, for a kernel whose
+    pool BlockSpec reads its tile id from scalar prefetch.
+
+    ``group`` i32[M] is each lane's tile; ``n_groups`` marks an idle lane.
+    Lanes are stably sorted by tile (lane order holds within a tile); step
+    s visits tile ``gid[s]`` and walks sorted lanes ``lo[s]:hi[s]``. Spare
+    steps (fewer touched tiles than ``n_steps``) revisit the last tile
+    with an empty range. Returns (order, sorted group, valid, lo, hi, gid).
+    """
+    M = group.shape[0]
+    order = jnp.argsort(group, stable=True)                      # keeps lanes
+    gs = group[order]
+    valid = gs < n_groups                          # a prefix after the sort
+    n_valid = valid.sum().astype(jnp.int32)
+    iota = jnp.arange(M, dtype=jnp.int32)
+    is_start = valid & jnp.concatenate([jnp.ones(1, bool), gs[1:] != gs[:-1]])
+    step = jnp.where(is_start, jnp.cumsum(is_start) - 1, n_steps)
+    lo = jnp.full(n_steps, n_valid, jnp.int32).at[step].set(iota, mode="drop")
+    hi = jnp.concatenate([lo[1:], n_valid[None]])
+    last = jnp.minimum(gs[jnp.maximum(n_valid - 1, 0)], n_groups - 1)
+    gid = jnp.full(n_steps, last, jnp.int32).at[step].set(gs, mode="drop")
+    return order, gs, valid, lo, hi, gid
+
+
+def _probe_kernel(gid_s, lo_s, hi_s, key_s, lane_s, pk_ref, pv_ref, found_ref,
+                  val_ref):
+    s = pl.program_id(0)
+    keys = pk_ref[...]                                  # [B, 128] of one tile
+    vals = pv_ref[...]
+    lanes = jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
+
+    def body(i, _):
+        key = key_s[i]
+        hit = (lanes == lane_s[i]) & (keys == key) & (key != _EMPTY)
+        found_ref[i] = jnp.max(hit.astype(jnp.int32))
+        val_ref[i] = jnp.sum(jnp.where(hit, vals, 0))
+        return 0
+
+    jax.lax.fori_loop(lo_s[s], hi_s[s], body, 0)
+
+
+def _probe_launch(bucket_ids, queries, pk_t, pv_t, interpret: bool):
+    """One launch over N ≤ _MAX_QUERIES queries and the transposed
+    [B, P+1] pools. Returns (found i32[N], val i32[N]) in query order."""
+    n = queries.shape[0]
+    b, p1 = pk_t.shape
+    n_tiles = pl.cdiv(p1, _LANES)
+    steps = min(n_tiles, n)
+    order, gs, _, lo, hi, gid = tile_schedule(bucket_ids // _LANES, n_tiles,
+                                              steps)
+    lane = bucket_ids[order] - gs * _LANES
+
+    tile = pl.BlockSpec((b, _LANES), lambda s, gid, *_: (0, gid[s]))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    found, val = pl.pallas_call(
+        _probe_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(steps,),
+            in_specs=[tile, tile],
+            out_specs=[smem, smem],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32)] * 2,
+        interpret=interpret,
+        name="probe",
+    )(gid, lo, hi, queries[order], lane, pk_t, pv_t)
+
+    def unsort(x):
+        return jnp.zeros_like(x).at[order].set(x)
+
+    return unsort(found), unsort(val)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def probe(bucket_ids: jnp.ndarray, queries: jnp.ndarray, pool_keys: jnp.ndarray,
+          pool_vals: jnp.ndarray, *, interpret: bool = True):
+    """Probe pool rows for `queries` routed to `bucket_ids`.
+
+    pool_keys/pool_vals are [R, B] pools, the whole ``[P+1, B]`` state
+    arrays included; only the tiles of 128 buckets that hold routed rows
+    move. Returns (found bool[N], vals i32[N] with -1 for misses).
+    """
+    pk_t, pv_t = pool_keys.T, pool_vals.T
+    n = queries.shape[0]
+    if n <= _MAX_QUERIES:
+        found, val = _probe_launch(bucket_ids, queries, pk_t, pv_t, interpret)
+    else:
+        pad = -n % _MAX_QUERIES
+
+        def chunks(x):
+            return jnp.pad(x, (0, pad)).reshape(-1, _MAX_QUERIES)
+
+        found, val = jax.lax.map(
+            lambda c: _probe_launch(*c, pk_t, pv_t, interpret),
+            (chunks(bucket_ids), chunks(queries)))
+        found, val = found.reshape(-1)[:n], val.reshape(-1)[:n]
+    found = found > 0
+    return found, jnp.where(found, val, -1)
+
+
+# ---------------------------------------------------------------------------
+# fused hash → directory-route → probe: the one-hot sweep
 
 
 def _dot(a, b):
@@ -67,12 +199,10 @@ def _gather32(onehot, x):
 
 
 def _probe_tile(q, b, pk_ref, pv_ref, found_ref, val_ref, j, pc: int):
-    """Shared probe body: accumulate one pool chunk's hits for a query tile.
+    """Accumulate one pool chunk's hits for a query tile of `fused_probe`.
 
     ``q`` and ``b`` are [TQ, 1] columns. One-hot gather via the MXU:
-    [TQ, PC] @ [PC, B] → [TQ, B]. Used by both the unfused (`_probe_kernel`)
-    and fused (`_fused_probe_kernel`) lookups — keep them in lockstep by
-    construction."""
+    [TQ, PC] @ [PC, B] → [TQ, B]."""
     local = b - j * pc                                   # [TQ, 1]
     in_chunk = (local >= 0) & (local < pc)
     tq = q.shape[0]
@@ -83,18 +213,6 @@ def _probe_tile(q, b, pk_ref, pv_ref, found_ref, val_ref, j, pc: int):
     eq = in_chunk & (rows_k == q) & (q != _EMPTY)        # [TQ, B]
     found_ref[...] += jnp.max(eq.astype(jnp.int32), axis=1, keepdims=True)
     val_ref[...] += jnp.sum(jnp.where(eq, rows_v, 0), axis=1, keepdims=True)
-
-
-def _probe_kernel(q_ref, b_ref, pk_ref, pv_ref, found_ref, val_ref, *, pc: int):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        found_ref[...] = jnp.zeros_like(found_ref)
-        val_ref[...] = jnp.zeros_like(val_ref)
-
-    _probe_tile(q_ref[...], b_ref[...], pk_ref, pv_ref, found_ref, val_ref,
-                j, pc)
 
 
 def _pad_queries(x, n_pad, fill):
@@ -110,47 +228,6 @@ def _pad_pool(pool_keys, pool_vals, pc):
 
 def _column_spec(tq: int):
     return pl.BlockSpec((tq, 1), lambda i, j: (i, 0))
-
-
-@functools.partial(jax.jit, static_argnames=("tq", "pc", "interpret"))
-def probe(bucket_ids: jnp.ndarray, queries: jnp.ndarray, pool_keys: jnp.ndarray,
-          pool_vals: jnp.ndarray, *, tq: int = 256, pc: int = 512,
-          interpret: bool = True):
-    """Probe pool rows for `queries` routed to `bucket_ids`.
-
-    Pads N to a multiple of tq and P to a multiple of pc; returns
-    (found bool[N], vals i32[N] with -1 for misses).
-    """
-    n = queries.shape[0]
-    n_pad = -n % tq
-    q = _pad_queries(queries, n_pad, EMPTY_KEY)
-    bid = _pad_queries(bucket_ids, n_pad, 0)
-    pk, pv = _pad_pool(pool_keys, pool_vals, pc)
-    b = pk.shape[1]
-    grid = ((n + n_pad) // tq, pk.shape[0] // pc)
-
-    found, val = pl.pallas_call(
-        functools.partial(_probe_kernel, pc=pc),
-        grid=grid,
-        in_specs=[
-            _column_spec(tq),                              # queries
-            _column_spec(tq),                              # bucket ids
-            pl.BlockSpec((pc, b), lambda i, j: (j, 0)),     # pool keys chunk
-            pl.BlockSpec((pc, b), lambda i, j: (j, 0)),     # pool vals chunk
-        ],
-        out_specs=[_column_spec(tq), _column_spec(tq)],
-        out_shape=[
-            jax.ShapeDtypeStruct((n + n_pad, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n + n_pad, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(q, bid, pk, pv)
-    found = found[:n, 0] > 0
-    return found, jnp.where(found, val[:n, 0], -1)
-
-
-# ---------------------------------------------------------------------------
-# fused hash → directory-route → probe
 
 
 # beyond this directory depth the directory block outgrows a comfortable
@@ -213,7 +290,7 @@ def _fused_probe_kernel(q_ref, dir_ref, pk_ref, pv_ref, found_ref, val_ref,
                     keepdims=True)
         bid_ref[...] = b.astype(jnp.int32)
 
-    # --- probe: shared tile body, bucket ids from the scratch stash ------
+    # --- probe: one-hot sweep, bucket ids from the scratch stash ---------
     _probe_tile(q_ref[...], bid_ref[...], pk_ref, pv_ref, found_ref, val_ref,
                 j, pc)
 

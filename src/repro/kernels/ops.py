@@ -68,8 +68,10 @@ def _kernel_lookup_impl(cfg: T.TableConfig, state: T.TableState, queries, *,
             tq=tq, pc=pc, dc=dc, interpret=interpret)
     h = cfg.hash_fn(queries)
     bid = state.directory[dir_index(h, cfg.dmax)]
-    return klookup.probe(bid, queries, state.keys[:-1], state.vals[:-1],
-                         tq=tq, pc=pc, interpret=interpret)
+    # the whole pools: only the tiles holding routed rows move, and the
+    # directory never routes to the trash row
+    return klookup.probe(bid, queries, state.keys, state.vals,
+                         interpret=interpret)
 
 
 def kernel_lookup(cfg: T.TableConfig, state: T.TableState, queries, *,
@@ -78,9 +80,11 @@ def kernel_lookup(cfg: T.TableConfig, state: T.TableState, queries, *,
 
     Fused hash→route→probe when the directory fits VMEM (the common case:
     dmax ≤ 17); otherwise the route runs in HBM and only the probe is a
-    kernel. Tiles resolve at every eager call (registry/env updates take
-    effect immediately — they become static args of the inner jit); the
-    facade's plan path (:func:`plan_lookup`) resolves them once instead."""
+    kernel, which fetches just the pool tiles the routed rows sit in; the
+    tiles below shape the fused probe only. Tiles resolve at every eager
+    call (registry/env updates take effect immediately — they become
+    static args of the inner jit); the facade's plan path
+    (:func:`plan_lookup`) resolves them once instead."""
     interpret = default_interpret() if interpret is None else interpret
     tiles = pick_tiles(queries.shape[0], cfg.pool_size, cfg.dcap,
                        key=tile_key("lookup", dmax=cfg.dmax,

@@ -41,14 +41,14 @@ def random_pool(rng, P, B, fill=0.5):
 # probe kernel
 
 
-@pytest.mark.parametrize("P,B,N,tq,pc", [
-    (8, 4, 16, 8, 8),
-    (64, 8, 100, 16, 32),     # non-divisible N → padding path
-    (130, 8, 257, 64, 64),    # non-divisible P
-    (32, 16, 64, 32, 32),
-    (512, 8, 512, 128, 256),
+@pytest.mark.parametrize("P,B,N", [
+    (8, 4, 16),
+    (64, 8, 100),      # N not a multiple of 8
+    (130, 8, 257),     # P not a multiple of the 128-bucket tile
+    (32, 16, 64),
+    (512, 8, 512),     # four tiles
 ])
-def test_probe_matches_ref_sweep(P, B, N, tq, pc):
+def test_probe_matches_ref_sweep(P, B, N):
     rng = np.random.default_rng(P * 1000 + N)
     pk, pv = random_pool(rng, P, B)
     bid = jnp.asarray(rng.integers(0, P, size=N), jnp.int32)
@@ -58,18 +58,18 @@ def test_probe_matches_ref_sweep(P, B, N, tq, pc):
     take = rng.random(N) < 0.5
     q = jnp.asarray(np.where(take & (present != EMPTY), present, miss))
     f_ref, v_ref = kref.probe_ref(bid, q, pk, pv)
-    f_k, v_k = probe(bid, q, pk, pv, tq=tq, pc=pc, interpret=True)
+    f_k, v_k = probe(bid, q, pk, pv, interpret=True)
     np.testing.assert_array_equal(np.asarray(f_k), np.asarray(f_ref))
     np.testing.assert_array_equal(np.asarray(v_k), np.asarray(v_ref))
 
 
 def test_probe_extreme_key_values():
-    """int32 extremes must survive the split-16 MXU gather exactly."""
+    """int32 extremes must survive the compare and the value path exactly."""
     pk = jnp.asarray([[2147483647, -2147483647, 1, EMPTY]], jnp.int32)
     pv = jnp.asarray([[-2147483648 + 1, 2147483647, -7, 0]], jnp.int32)
     bid = jnp.zeros(4, jnp.int32)
     q = jnp.asarray([2147483647, -2147483647, 1, 12345], jnp.int32)
-    f, v = probe(bid, q, pk, pv, tq=8, pc=8, interpret=True)
+    f, v = probe(bid, q, pk, pv, interpret=True)
     np.testing.assert_array_equal(np.asarray(f), [True, True, True, False])
     np.testing.assert_array_equal(np.asarray(v)[:3],
                                   [-2147483647, 2147483647, -7])
@@ -78,7 +78,7 @@ def test_probe_extreme_key_values():
 @settings(max_examples=25, deadline=None)
 @given(st.data())
 def test_probe_hypothesis(data):
-    P = data.draw(st.sampled_from([4, 16, 64]))
+    P = data.draw(st.sampled_from([4, 16, 64, 200]))
     B = data.draw(st.sampled_from([4, 8]))
     N = data.draw(st.integers(1, 80))
     rng = np.random.default_rng(data.draw(st.integers(0, 2**31)))
@@ -86,9 +86,74 @@ def test_probe_hypothesis(data):
     bid = jnp.asarray(rng.integers(0, P, size=N), jnp.int32)
     q = jnp.asarray(rng.integers(-(1 << 31) + 1, 1 << 31, size=N), jnp.int32)
     f_ref, v_ref = kref.probe_ref(bid, q, pk, pv)
-    f_k, v_k = probe(bid, q, pk, pv, tq=16, pc=16, interpret=True)
+    f_k, v_k = probe(bid, q, pk, pv, interpret=True)
     np.testing.assert_array_equal(np.asarray(f_k), np.asarray(f_ref))
     np.testing.assert_array_equal(np.asarray(v_k), np.asarray(v_ref))
+
+
+def _whole_pools(rng, P, B):
+    """The table's [P+1, B] pools: P random buckets and the empty trash
+    row, which the directory never routes to."""
+    pk, pv = random_pool(rng, P, B)
+    pk = jnp.concatenate([pk, jnp.full((1, B), EMPTY, jnp.int32)])
+    pv = jnp.concatenate([pv, jnp.zeros((1, B), jnp.int32)])
+    return pk, pv
+
+
+def _present(rng, pk, bid):
+    """A key stored in each routed bucket (a miss where the slot drawn is
+    empty)."""
+    B = pk.shape[1]
+    k = np.asarray(pk)[bid, rng.integers(0, B, size=bid.size)]
+    return np.where(k != EMPTY, k, 20_000 + np.arange(bid.size)).astype(
+        np.int32)
+
+
+@pytest.mark.parametrize("case", ["one_tile", "last_tile", "duplicates",
+                                  "empty_key", "padding", "odd_n",
+                                  "two_launches"])
+def test_probe_whole_pools(case):
+    """The routed probe over whole [P+1, B] pools (301 buckets: three tiles
+    of 128, the last one partial and holding the trash row)."""
+    P, B = 300, 8
+    rng = np.random.default_rng(len(case))
+    pk, pv = _whole_pools(rng, P, B)
+    if case == "one_tile":            # 200 queries on one tile, fetched once
+        bid = rng.integers(128, 256, size=200)
+    elif case == "last_tile":         # up to the row next to the trash row
+        bid = np.concatenate([[P - 1, P - 1, 256], rng.integers(256, P, 61)])
+    elif case == "duplicates":        # 16 keys, each asked 8 times
+        bid = np.repeat(rng.integers(0, P, size=16), 8)
+    elif case == "two_launches":      # longer than one launch's SMEM budget
+        bid = rng.integers(0, P, size=8192 + 100)
+    else:
+        bid = rng.integers(0, P, size=37 if case == "odd_n" else 64)
+    q = _present(rng, pk, bid)
+    if case == "duplicates":
+        perm = rng.permutation(bid.size)
+        bid, q = bid[perm], q[perm]
+    elif case == "empty_key":         # the empty-slot marker never matches
+        q[::2] = EMPTY
+    elif case == "padding":           # a batch padded with key-0 lanes
+        pad = 512 - bid.size
+        b0 = 77
+        pk = pk.at[b0, 3].set(0)
+        pv = pv.at[b0, 3].set(4242)
+        bid = np.concatenate([bid, np.full(pad, b0)])
+        q = np.concatenate([q, np.zeros(pad, np.int32)])
+    bid = jnp.asarray(bid, jnp.int32)
+    q = jnp.asarray(q, jnp.int32)
+    f_ref, v_ref = kref.probe_ref(bid, q, pk, pv)
+    f_ref = f_ref & (q != EMPTY)
+    v_ref = jnp.where(f_ref, v_ref, -1)
+    f_k, v_k = probe(bid, q, pk, pv, interpret=True)
+    np.testing.assert_array_equal(np.asarray(f_k), np.asarray(f_ref))
+    np.testing.assert_array_equal(np.asarray(v_k), np.asarray(v_ref))
+    assert np.asarray(f_k).any()
+    if case == "empty_key":
+        assert not np.asarray(f_k)[::2].any()
+    if case == "padding":
+        assert np.asarray(f_k)[-1] and np.asarray(v_k)[-1] == 4242
 
 
 # ---------------------------------------------------------------------------

@@ -9,7 +9,7 @@ the kernels with ``interpret=False`` at the sizes ``chip_smoke.py`` runs:
 * phase-2 geometry (dmax 17, P 131071 — the fused plan): ``fused_probe``
   and ``fused_apply`` at 16 and 512 lanes;
 * phase-3 geometry (dmax 20, P 2**20 — beyond the fused bounds): ``probe``
-  and ``grouped_apply``;
+  over the whole ``[P+1, B]`` pools, and ``grouped_apply``;
 * the facade's jitted apply and lookup under an explicitly constructed
   TPU ``KernelPlan``.
 
@@ -21,6 +21,7 @@ described chip cannot be read back without one.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,11 +90,19 @@ def test_fused_apply_compiles(chip, n):
     assert "tpu_custom_call" in text
 
 
-def test_probe_compiles(chip):
+@pytest.mark.parametrize("n", [512, 2 * 8192 + 5])
+def test_probe_compiles(chip, n):
+    """The routed probe over the whole [P+1, B] pools: a Mosaic kernel whose
+    instruction is named ``probe`` (the name the benchmark files under
+    lookup), fed the pools as bitcasts of their HBM layout, not copies.
+    The longer batch runs in launches of 8192 queries."""
     fn = functools.partial(klookup.probe, interpret=False)
-    n, p = 4096, LARGE["pool_size"]
-    text = _compiled_text(chip, fn, _i32(n), _i32(n), _i32(p, B), _i32(p, B))
-    assert "tpu_custom_call" in text
+    p1 = LARGE["pool_size"] + 1
+    text = _compiled_text(chip, fn, _i32(n), _i32(n), _i32(p1, B),
+                          _i32(p1, B))
+    assert re.search(r"%probe(\.\d+)? = .*custom_call_target=\"tpu_custom_call\"",
+                     text)
+    assert not re.search(r"= s32\[(8,\d{7,}|\d{7,},8)\]\S* copy\(", text)
 
 
 def test_grouped_apply_compiles(chip):
